@@ -56,6 +56,7 @@ from repro.core.besf import BitStopperConfig, besf_attention_decode, \
     besf_attention_decode_paged
 from repro.kernels.paged_decode import paged_bitstopper_decode
 from repro.launch.hlo_cost import analyze_hlo
+from repro.launch.mesh import make_serve_mesh
 from repro.launch.roofline import HBM_BW, PEAK_FLOPS
 from repro.models.attention import gather_paged_view
 
@@ -112,12 +113,13 @@ def build_pool_state(B, MB, bs, Hkv, D, seed=0):
                 np.asarray(vh).reshape(MB, bs, D)
             q[b, h] = np.asarray(qh)[0]
     table = 1 + np.arange(B * MB, dtype=np.int32).reshape(B, MB)
-    k_pool, v_pool = jnp.asarray(k_pool), jnp.asarray(v_pool)
+    # lane-dense [P, bs, Hkv*D], as the serving cache stores them
     return dict(
-        q=jnp.asarray(q), k_pool=k_pool, v_pool=v_pool,
+        q=jnp.asarray(q), k_pool=jnp.asarray(k_pool.reshape(P, bs, -1)),
+        v_pool=jnp.asarray(v_pool.reshape(P, bs, -1)),
         table=jnp.asarray(table),
-        k_amax=jnp.max(jnp.abs(k_pool), axis=(0, 1, 3)),
-        v_amax=jnp.max(jnp.abs(v_pool), axis=(0, 1, 3)),
+        k_amax=jnp.asarray(np.abs(k_pool).max(axis=(0, 1, 3))),
+        v_amax=jnp.asarray(np.abs(v_pool).max(axis=(0, 1, 3))),
     )
 
 
@@ -150,7 +152,7 @@ def bench_config(state, bs, fill, cfg, reps, run_kernel):
     q, k_pool, v_pool = state["q"], state["k_pool"], state["v_pool"]
     table = state["table"]
     B, MB = table.shape
-    Hkv, D = k_pool.shape[2], k_pool.shape[3]
+    Hkv, D = state["k_amax"].shape[0], q.shape[-1]
     Tv = MB * bs
     itemsize = k_pool.dtype.itemsize
     n_live = max(1, round(MB * fill))
@@ -167,8 +169,8 @@ def bench_config(state, bs, fill, cfg, reps, run_kernel):
     @jax.jit
     def gather_step(q):
         k_view, v_view, _ = gather_paged_view(cache)
-        kr = k_view.swapaxes(1, 2)                         # G == 1
-        vr = v_view.swapaxes(1, 2)
+        kr = k_view.reshape(B, Tv, Hkv, D).swapaxes(1, 2)  # G == 1
+        vr = v_view.reshape(B, Tv, Hkv, D).swapaxes(1, 2)
         mask = (jnp.arange(Tv)[None] < lengths[:, None])[:, None, None, :]
         return besf_attention_decode(q[:, :, None], kr, vr, cfg=cfg,
                                      mask=mask).out
@@ -177,6 +179,8 @@ def bench_config(state, bs, fill, cfg, reps, run_kernel):
     @jax.jit
     def flash_step(q):
         k_view, v_view, _ = gather_paged_view(cache)
+        k_view = k_view.reshape(B, Tv, Hkv, D)
+        v_view = v_view.reshape(B, Tv, Hkv, D)
         mask = jnp.arange(Tv)[None] < lengths[:, None]
         logits = jnp.einsum("bhd,bthd->bht", q, k_view) / D ** 0.5
         logits = jnp.where(mask[:, None], logits, -1e30)
@@ -351,8 +355,9 @@ def run_sharded(args, cfg, bs, B, Hkv, D, MB, fills, reps, mesh):
         per_dev = []
         for s in range(tp):
             hs = slice(s * Hl, (s + 1) * Hl)
+            ls = slice(s * Hl * D, (s + 1) * Hl * D)        # its lanes
             st = besf_attention_decode_paged(
-                q[:, hs], k_pool[:, :, hs], v_pool[:, :, hs], table,
+                q[:, hs], k_pool[:, :, ls], v_pool[:, :, ls], table,
                 lengths, q_pos, state["k_amax"][hs], state["v_amax"][hs],
                 cfg=cfg)
             per_dev.append(model_bytes(st, Hl))
@@ -465,7 +470,7 @@ def main():
                      f"{n_dev} visible (use --host-devices on CPU)")
         if Hkv % tp != 0:
             ap.error(f"--mesh tp={tp} must divide n_kv_heads={Hkv}")
-        mesh = jax.make_mesh((dp, tp), ("data", "model"))
+        mesh = make_serve_mesh(dp, tp)
 
     def measure():
         rows = run_sweep(args, cfg, bs, B, Hkv, D, mbs, fills, reps)

@@ -63,6 +63,7 @@ import numpy as np
 
 from repro.configs import reduced_config
 from repro.core.besf import BitStopperConfig
+from repro.launch.mesh import make_serve_mesh
 from repro.models import transformer as T
 from repro.serving import (
     ContinuousBatchingEngine,
@@ -804,7 +805,7 @@ def main():
         if dp * tp > len(jax.devices()):
             ap.error(f"--mesh {dp},{tp} needs {dp * tp} devices, "
                      f"{len(jax.devices())} visible (use --host-devices)")
-        mesh = jax.make_mesh((dp, tp), ("data", "model"))
+        mesh = make_serve_mesh(dp, tp)
 
     kw = dict(arch=args.arch, impl=args.impl, alpha=args.alpha,
               n_requests=args.requests, slots=args.slots, seed=args.seed)

@@ -20,6 +20,8 @@ checked *statically*:
   cases) blocked dims respect the TPU native tile: a blocked last dim is
   the full dim or a multiple of 128, a blocked sublane dim the full dim
   or a multiple of the dtype's min sublane (f32 8, bf16 16, int8 32).
+  A block dim of 1 is held to the same rule — Mosaic refuses a (1, n)
+  block of a taller array like any other partial tile.
 * ``kernel-scalar-prefetch``   — ``PrefetchScalarGridSpec`` scalar
   operands are integer arrays (they become SMEM DMA addressing).
 * ``kernel-interpret-routing`` — the entry resolved ``interpret``
@@ -245,15 +247,17 @@ def check_record(rec: PallasCallRecord, *,
             continue
         vmem_bytes += _size_bytes(block, aval.dtype)
         if tile_check and len(block) >= 2:
+            # A unit block dim is no exemption: Mosaic refuses a (1, n)
+            # block over a taller array just like any other partial tile.
             b_lane, s_lane = block[-1], aval.shape[-1]
-            if b_lane > 1 and b_lane != s_lane and b_lane % _LANE:
+            if b_lane != s_lane and b_lane % _LANE:
                 report("kernel-tile-multiple",
                        f"{label}: blocked last dim {b_lane} is neither "
                        f"the full dim ({s_lane}) nor a multiple of "
                        f"{_LANE} lanes")
             b_sub, s_sub = block[-2], aval.shape[-2]
             sub = _min_sublane(aval.dtype)
-            if b_sub > 1 and b_sub != s_sub and b_sub % sub:
+            if b_sub != s_sub and b_sub % sub:
                 report("kernel-tile-multiple",
                        f"{label}: blocked sublane dim {b_sub} is neither "
                        f"the full dim ({s_sub}) nor a multiple of the "
@@ -362,6 +366,27 @@ class KernelContract:
     cases: Callable[[], list[Case]]
 
 
+def _paged_pools(P, bits, bs, Hkv, D):
+    """The serving cache's lane-dense plane and V pools (init_cache)."""
+    from repro.core.quantization import plane_pool_shape
+    return (jnp.zeros(plane_pool_shape(P, bits, bs, Hkv * D), jnp.uint8),
+            jnp.zeros((P, bs, Hkv * D), jnp.float32))
+
+
+def _paged_scratch(Sq, Hq, Hkv, D, bs, bits):
+    """Scratch of the shared paged BESF kernel (kernels/paged_decode.py):
+    one page's plane and V page as the pools store them, lane-dense."""
+    kq, v = _paged_pools(1, bits, bs, Hkv, D)
+    SH = Sq * Hq
+    return [((2,) + kq.shape[2:], jnp.uint8),
+            (v.shape[1:], jnp.float32),
+            ((SH, bs), jnp.int32),
+            ((SH, 1), jnp.float32),
+            ((SH, 1), jnp.float32),
+            ((SH, 1), jnp.float32),
+            ((SH, Hkv * D), jnp.float32)]
+
+
 def _decode_cases() -> list[Case]:
     from repro.core.besf import BitStopperConfig
     from repro.kernels import paged_decode as m
@@ -371,28 +396,20 @@ def _decode_cases() -> list[Case]:
     def mk(B, Hq, Hkv, D, bs, MB, P, window, stats, tile_check=False):
         def run():
             q = jnp.ones((B, Hq, D), jnp.float32)
-            kq = jnp.zeros((P, bits, bs // 8, Hkv, D), jnp.uint8)
-            v = jnp.zeros((P, bs, Hkv, D), jnp.float32)
+            kq, v = _paged_pools(P, bits, bs, Hkv, D)
             amax = jnp.ones((Hkv,), jnp.float32)
             m.paged_bitstopper_decode.__wrapped__(
                 q, kq, v, jnp.zeros((B, MB), jnp.int32),
                 jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
                 amax, amax, cfg=cfg, window=window, stats=stats)
 
-        def scratch():
-            return [((2, bs // 8, Hkv, D), jnp.uint8),
-                    ((bs, Hkv, D), jnp.float32),
-                    ((Hq, bs), jnp.int32),
-                    ((Hq,), jnp.float32),
-                    ((Hq,), jnp.float32),
-                    ((Hq,), jnp.float32),
-                    ((Hq, D), jnp.float32)]
-
         return Case(
             label=(f"decode B{B} Hq{Hq} Hkv{Hkv} D{D} bs{bs} MB{MB} "
                    f"win{window} stats{stats}"),
-            run=run, expected_scratch=scratch, expected_sems=2,
-            tile_check=tile_check)
+            run=run,
+            expected_scratch=lambda: _paged_scratch(1, Hq, Hkv, D, bs,
+                                                    bits),
+            expected_sems=2, tile_check=tile_check)
 
     return [
         mk(2, 4, 2, 8, 8, 3, 5, None, True),
@@ -405,6 +422,11 @@ def _decode_cases() -> list[Case]:
         # Hq4/Hkv2 case above, down to a single local KV head.
         mk(1, 2, 1, 8, 8, 3, 5, None, True),
         mk(1, 4, 1, 128, 128, 2, 4, None, True, tile_check=True),
+        # stablelm-1.6b as the engine serves it (32 x 64 heads, page 16,
+        # 4 slots, 67-page tables for max_len 1064) and its tp=4
+        # per-shard geometry.
+        mk(4, 32, 32, 64, 16, 67, 269, None, False, tile_check=True),
+        mk(4, 8, 8, 64, 16, 67, 269, None, False, tile_check=True),
     ]
 
 
@@ -415,12 +437,9 @@ def _verify_cases() -> list[Case]:
     bits = cfg.bits
 
     def mk(B, Sq, Hq, Hkv, D, bs, MB, P, window, stats, tile_check=False):
-        SH = Sq * Hq
-
         def run():
             q = jnp.ones((B, Sq, Hq, D), jnp.float32)
-            kq = jnp.zeros((P, bits, bs // 8, Hkv, D), jnp.uint8)
-            v = jnp.zeros((P, bs, Hkv, D), jnp.float32)
+            kq, v = _paged_pools(P, bits, bs, Hkv, D)
             amax = jnp.ones((Hkv,), jnp.float32)
             m.paged_bitstopper_verify.__wrapped__(
                 q, kq, v, jnp.zeros((B, MB), jnp.int32),
@@ -428,20 +447,13 @@ def _verify_cases() -> list[Case]:
                 jnp.zeros((B, Sq), jnp.int32),
                 amax, amax, cfg=cfg, window=window, stats=stats)
 
-        def scratch():
-            return [((2, bs // 8, Hkv, D), jnp.uint8),
-                    ((bs, Hkv, D), jnp.float32),
-                    ((SH, bs), jnp.int32),
-                    ((SH,), jnp.float32),
-                    ((SH,), jnp.float32),
-                    ((SH,), jnp.float32),
-                    ((SH, D), jnp.float32)]
-
         return Case(
             label=(f"verify B{B} Sq{Sq} Hq{Hq} Hkv{Hkv} D{D} bs{bs} "
                    f"MB{MB} win{window} stats{stats}"),
-            run=run, expected_scratch=scratch, expected_sems=2,
-            tile_check=tile_check)
+            run=run,
+            expected_scratch=lambda: _paged_scratch(Sq, Hq, Hkv, D, bs,
+                                                    bits),
+            expected_sems=2, tile_check=tile_check)
 
     return [
         mk(2, 2, 2, 1, 8, 8, 2, 4, None, True),
@@ -452,6 +464,9 @@ def _verify_cases() -> list[Case]:
         # hold its contract at local Hkv = 1 with grouped Q heads.
         mk(1, 3, 2, 1, 16, 8, 3, 5, 4, False),
         mk(1, 2, 2, 1, 128, 128, 2, 4, None, True, tile_check=True),
+        # stablelm-1.6b draft blocks (k=4 -> Sq=5), full and tp=4 shard.
+        mk(4, 5, 32, 32, 64, 16, 67, 269, None, False, tile_check=True),
+        mk(4, 5, 8, 8, 64, 16, 67, 269, None, False, tile_check=True),
     ]
 
 

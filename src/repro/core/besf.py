@@ -20,6 +20,7 @@ numbers without re-running the algorithm.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import NamedTuple
 
@@ -299,8 +300,8 @@ def _paged_decode_row(
     table,                  # [MB] int32 — logical block -> physical block
     length,                 # int32 — row fill level (tokens cached)
     q_pos,                  # int32 — absolute position of the query
-    k_pool,                 # [P, bs, Hkv, d] f32
-    v_pool,                 # [P, bs, Hkv, dv] f32
+    k_pool,                 # [P, bs, Hkv, d] or lane-dense [P, bs, Hkv*d]
+    v_pool,                 # [P, bs, Hkv, dv] or lane-dense [P, bs, Hkv*dv]
     k_scale, v_scale,       # [Hkv] f32
     cfg: BitStopperConfig,
     window: int | None,
@@ -316,7 +317,9 @@ def _paged_decode_row(
     all rounds.  The softmax is the flash-style online rescale in page
     order — mirroring the kernel's epilogue op for op."""
     Hq, d = q_int.shape
-    P, bs, Hkv, dv = v_pool.shape
+    bs = v_pool.shape[1]
+    Hkv = k_scale.shape[0]
+    dv = math.prod(v_pool.shape[2:]) // Hkv
     MB = table.shape[0]
     bits = cfg.bits
     G = Hq // Hkv
@@ -345,7 +348,8 @@ def _paged_decode_row(
         mlow, m_run, l_run, acc = carry
         phys = table[j]
         k_int = qlib.quantize_with_scale(
-            k_pool[phys], k_scale[None, :, None], bits)       # [bs, Hkv, d]
+            k_pool[phys].reshape(bs, Hkv, d), k_scale[None, :, None],
+            bits)                                             # [bs, Hkv, d]
         planes = qlib.to_bitplanes(k_int, bits)               # [bits,bs,Hkv,d]
         valid_b = jnp.broadcast_to(valid[None], (Hq, bs))
 
@@ -384,7 +388,7 @@ def _paged_decode_row(
         p = jnp.where(survived, jnp.exp(logits - m_new[:, None]), 0.0)
         corr = jnp.where(m_run == NEG_INF, 0.0, jnp.exp(m_run - m_new))
         l_new = l_run * corr + jnp.sum(p, axis=-1)
-        vblk = v_pool[phys]                                   # [bs, Hkv, dv]
+        vblk = v_pool[phys].reshape(bs, Hkv, dv)
         if cfg.quantize_v:
             v_eff = (qlib.quantize_with_scale(
                 vblk, v_scale[None, :, None], bits).astype(jnp.float32)
@@ -416,8 +420,8 @@ def _paged_decode_row(
 @partial(jax.jit, static_argnames=("cfg", "window"))
 def besf_attention_decode_paged(
     q: jax.Array,            # [B, Hq, d] — one decode query per slot
-    k_pool: jax.Array,       # [P, page_size, Hkv, d] f32 pool
-    v_pool: jax.Array,       # [P, page_size, Hkv, dv] f32 pool
+    k_pool: jax.Array,       # [P, page_size, Hkv*d] (or [.., Hkv, d]) pool
+    v_pool: jax.Array,       # [P, page_size, Hkv*dv] (or [.., Hkv, dv])
     table: jax.Array,        # [B, MB] int32 block tables
     lengths: jax.Array,      # [B] int32 fill levels
     q_positions: jax.Array,  # [B] int32 absolute query positions
@@ -437,7 +441,7 @@ def besf_attention_decode_paged(
     (``k_amax``/``v_amax``), not per-row view scales: a physical page
     shared by several block tables (prefix sharing) or recycled across
     requests must mean the same integers to every reader."""
-    Hkv = k_pool.shape[2]
+    Hkv = k_amax.shape[0]
     prep = paged_decode_prep(q, k_amax, v_amax, Hkv, cfg)
     q_int, m_min, m_max, scale_total, alpha_radius, k_scale, v_scale = prep
     # lax.map (sequential over rows), NOT vmap: vmap would batch the
@@ -464,8 +468,8 @@ class PagedVerifyOutput(NamedTuple):
 @partial(jax.jit, static_argnames=("cfg", "window"))
 def besf_attention_verify_paged(
     q: jax.Array,            # [B, Sq, Hq, d] — the draft block per slot
-    k_pool: jax.Array,       # [P, page_size, Hkv, d] f32 pool
-    v_pool: jax.Array,       # [P, page_size, Hkv, dv] f32 pool
+    k_pool: jax.Array,       # [P, page_size, Hkv*d] (or [.., Hkv, d]) pool
+    v_pool: jax.Array,       # [P, page_size, Hkv*dv] (or [.., Hkv, dv])
     table: jax.Array,        # [B, MB] int32 block tables (shared by queries)
     lengths: jax.Array,      # [B, Sq] int32 per-QUERY fill levels
     q_positions: jax.Array,  # [B, Sq] int32 absolute query positions
@@ -497,7 +501,7 @@ def besf_attention_verify_paged(
     ANY query still needs them) while keeping per-query liveness for every
     observable."""
     B, Sq, Hq, d = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = k_amax.shape[0]
     MB = table.shape[1]
     flat_q = q.reshape(B * Sq, Hq, d)
     prep = paged_decode_prep(flat_q, k_amax, v_amax, Hkv, cfg)

@@ -16,6 +16,7 @@ non-negative amount, which is what makes the bit-level uncertainty margin
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 
 import jax
@@ -138,26 +139,64 @@ def unpack_planes_seq(packed: jax.Array) -> jax.Array:
     return u.reshape(bits, S8 * 8, d).astype(jnp.uint8)
 
 
+def plane_chunks(page_size: int) -> int:
+    """Head-dim chunks per byte-group row of the lane-dense plane pool.
+
+    One page's plane is ``page_size // 8`` byte groups (8 tokens per byte)
+    of ``H * D`` bytes.  A manual DMA of it is a whole number of (8, 128)
+    tiles only if it spans a multiple of 8 rows, so each byte group is cut
+    into ``C`` head-dim chunks, one row each: ``lcm(page_size // 8, 8)``
+    rows per plane (8 at page 16)."""
+    bs8 = page_size // 8
+    return math.lcm(bs8, 8) // bs8
+
+
+def plane_pool_shape(pool_blocks: int, bits: int, page_size: int,
+                     lanes: int) -> tuple[int, int, int, int]:
+    """Shape of the lane-dense plane pool (:func:`pack_pool_planes`) of a
+    ``[pool_blocks, page_size, lanes]`` K pool, ``lanes = H * D``."""
+    C = plane_chunks(page_size)
+    return (pool_blocks, bits, page_size // 8 * C, lanes // C)
+
+
 def pack_pool_planes(pool: jax.Array, amax: jax.Array,
                      bits: int = DEFAULT_BITS) -> jax.Array:
     """Quantize + bit-plane-pack a whole paged K pool in one shot.
 
-    ``pool`` f32 ``[P, page_size, H, D]`` (page_size % 8 == 0), ``amax``
-    ``[H]`` pool-wide running max-abs → ``uint8[P, bits, page_size//8, H,
-    D]`` with token t of a page owning bit ``t % 8`` of byte ``t // 8``
-    (LSB-first, the :func:`pack_planes_seq` layout).  This is the canonical
-    definition the incremental write path, the paged decode kernel, and
-    the benchmarks all share — the rescale-on-demand requant rebuilds the
-    serving plane pool with exactly this function."""
-    P, bs, H, D = pool.shape
+    ``pool`` f32 ``[P, page_size, H, D]`` or lane-dense ``[P, page_size,
+    H*D]`` (page_size % 8 == 0), ``amax`` ``[H]`` pool-wide running
+    max-abs.  Token t of a page owns bit ``t % 8`` of byte group ``t // 8``
+    (LSB-first, the :func:`pack_planes_seq` layout).  The result is the
+    serving cache's lane-dense plane pool ``uint8[P, bits, R, H*D/C]``
+    (``C = plane_chunks(page_size)``, ``R = page_size//8 * C``): row
+    ``b*C + c`` of a plane holds byte group ``b``'s dims ``d = c*X + x``
+    (``X = D/C``) at lane ``h*X + x`` — each KV head owns a contiguous lane
+    range of every row, so splitting the lanes splits heads.
+
+    This is the canonical definition the incremental write path, the paged
+    decode kernel, and the benchmarks all share — the rescale-on-demand
+    requant rebuilds the serving plane pool with exactly this function."""
+    P, bs = pool.shape[:2]
+    H = amax.shape[0]
     assert bs % 8 == 0, f"page size {bs} not a multiple of 8"
     scale = scale_from_amax(amax, bits)
-    q = quantize_with_scale(pool, scale[None, None, :, None], bits)
+    q = quantize_with_scale(pool.reshape(P, bs, H, -1),
+                            scale[None, None, :, None], bits)
     planes = to_bitplanes(q, bits)                  # [bits, P, bs, H, D]
-    pk = planes.reshape(bits, P, bs // 8, 8, H, D).astype(jnp.uint32)
+    pk = planes.reshape(bits, P, bs // 8, 8, H, -1).astype(jnp.uint32)
     weights = (jnp.uint32(1) << jnp.arange(8, dtype=jnp.uint32))
     packed = jnp.sum(pk * weights.reshape(1, 1, 1, 8, 1, 1), axis=3)
-    return packed.astype(jnp.uint8).transpose(1, 0, 2, 3, 4)
+    rows = to_plane_rows(packed.astype(jnp.uint8), plane_chunks(bs))
+    return rows.reshape(bits, P, -1, rows.shape[-1]).transpose(1, 0, 2, 3)
+
+
+def to_plane_rows(x: jax.Array, chunks: int) -> jax.Array:
+    """``[..., H, D]`` -> ``[..., C, H*D/C]``: one byte group's (or one
+    token's) head-split plane values in the row/lane order of the
+    lane-dense plane pool (see :func:`pack_pool_planes`)."""
+    *lead, H, D = x.shape
+    x = x.reshape(*lead, H, chunks, D // chunks)
+    return jnp.swapaxes(x, -3, -2).reshape(*lead, chunks, H * (D // chunks))
 
 
 @partial(jax.jit, static_argnames=("bits",))

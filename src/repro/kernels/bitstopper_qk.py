@@ -67,7 +67,7 @@ def _bitstopper_kernel(
     v_hbm,                  # [Sk, dv] f32
     # outputs
     out_ref,                # [block_q, dv]
-    rounds_ref,             # [1, 1] int32
+    rounds_ref,             # [1, 1, n_kb] int32 — this q tile's row
     surv_ref,               # [block_q, block_k] int8
     # scratch
     plane_ref,              # [2, block_k//8, d] uint8 (double buffer)
@@ -188,7 +188,14 @@ def _bitstopper_kernel(
         (tok0, blk0, jnp.zeros((), jnp.int32), mlow_ref[...]),
     )
     mlow_ref[...] = mlow
-    rounds_ref[0, 0] = rounds
+    # Lane ki of the row resident across the kv axis: no scalar store to
+    # VMEM (Mosaic refuses one) and no (1, 1) block of a taller array.
+    @pl.when(ki == 0)
+    def _clear_rounds():
+        rounds_ref[...] = jnp.zeros_like(rounds_ref)
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, rounds_ref.shape[1:], 1)
+    rounds_ref[0] = jnp.where(lane == ki, rounds, rounds_ref[0])
 
     # Survivors: alive tokens of a block that completed every round hold
     # their exact INT12 scores (stage fusion: prediction work == execution).
@@ -267,12 +274,12 @@ def _bitstopper_single(
         ],
         out_specs=[
             pl.BlockSpec((block_q, dv), lambda qi, ki: (qi, 0)),
-            pl.BlockSpec((1, 1), lambda qi, ki: (qi, ki)),
+            pl.BlockSpec((1, 1, n_kb), lambda qi, ki: (qi, 0, 0)),
             pl.BlockSpec((block_q, block_k), lambda qi, ki: (qi, ki)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Sq, dv), v_eff.dtype),
-            jax.ShapeDtypeStruct((n_qt, n_kb), jnp.int32),
+            jax.ShapeDtypeStruct((n_qt, 1, n_kb), jnp.int32),
             jax.ShapeDtypeStruct((Sq, Sk), jnp.int8),
         ],
         scratch_shapes=[
@@ -288,7 +295,7 @@ def _bitstopper_single(
         ],
         interpret=interpret,
     )(scalars, q_int, m_min, m_max, k_packed, v_eff)
-    return KernelOutput(out=out, rounds=rounds, survivors=surv)
+    return KernelOutput(out=out, rounds=rounds[:, 0], survivors=surv)
 
 
 @functools.partial(

@@ -1,42 +1,26 @@
-"""Fused Sq-tiled paged BESF verify Pallas kernel — speculative decoding's
-draft-block scorer.
+"""Fused Sq-tiled paged BESF verify — speculative decoding's draft-block
+scorer.
 
-This is the multi-query generalization of ``kernels/paged_decode.py``:
-instead of one query per serving slot, a slot brings an Sq-token **draft
-block** ([last sampled token, draft 1, ..., draft k]) and every query must
-be scored exactly as the Sq=1 decode kernel would have scored it at that
-position.  The payoff over running the decode kernel Sq times is the
-paper's stage-fusion argument applied across the draft block:
+A slot brings an Sq-token **draft block** ([last sampled token, draft 1,
+..., draft k]) and every query must be scored exactly as the Sq=1 decode
+would have scored it at that position.  The payoff over running the
+decode kernel Sq times is the paper's stage-fusion argument applied
+across the draft block: one plane DMA per (page, round) serves every
+query that still wants it (union liveness), and one V DMA per page serves
+every query with survivors, while liveness, margins, plane counts and
+survivors stay per (query, head) — bit for bit those of the pure-JAX
+oracle ``core/besf.py:besf_attention_verify_paged``, which routes each
+(slot, query) through the very ``_paged_decode_row`` the Sq=1 paths share.
 
-* **One plane DMA per (page, round) for the whole block.**  The packed
-  bit-plane page is fetched when *any* query's LATS state still wants it
-  (union liveness) and then consumed by every live query — the prediction
-  traffic of verifying k draft tokens is amortized to ~1x the Sq=1 cost
-  instead of k+1 separate fetches.
-* **Per-query LATS, bit for bit.**  Liveness, margins, prefix-max lower
-  bounds, plane counts and survivors are tracked per (query, head):
-  observables match the pure-JAX oracle
-  ``core/besf.py:besf_attention_verify_paged`` — which routes each (slot,
-  query) through the very ``_paged_decode_row`` the Sq=1 paths share —
-  bit for bit (tested).  A query whose pages all died keeps its state
-  frozen even while its neighbours keep fetching.
-* **Causal intra-draft masking via per-query fill levels.**  Query i at
-  absolute position p sees cached tokens ``t_pos <= p`` — earlier draft
-  tokens (already scattered into the pool by the batched cache write) but
-  never later ones.  Padding queries (a slot that proposed fewer than k
-  drafts) ride along with fill level 0: every page is dead for them, they
-  fetch nothing.
-* **Early-terminated V, shared.**  A page's V is DMA'd once if at least
-  one query has survivors; each query's online-softmax epilogue is
-  predicated on its *own* survivors, exactly like the oracle.
+Causal intra-draft masking comes from per-query fill levels: query i at
+absolute position p sees cached tokens ``t_pos <= p`` — earlier draft
+tokens (already scattered into the pool by the batched cache write) but
+never later ones.  Padding queries (a slot that proposed fewer than k
+drafts) ride along with fill level 0: every page is dead for them and
+they fetch nothing.
 
-Over-accumulation note: a query whose page died keeps receiving plane
-deltas into the shared partial-score scratch (the plane was fetched for a
-live neighbour).  This is unobservable — the oracle proves it: pruned
-candidates' partials feed neither thresholds (frozen ``keep``), nor
-``mlow`` (gated on the query's own page liveness), nor logits (survivors
-require all ``bits`` rounds, in which case both versions accumulated every
-plane).
+The kernel itself is shared with the Sq=1 decode; its design notes live
+in ``kernels/paged_decode.py``.
 """
 
 from __future__ import annotations
@@ -45,215 +29,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import quantization as qlib
-from repro.core.besf import BitStopperConfig, PagedVerifyOutput, \
-    paged_decode_prep
+from repro.core.besf import BitStopperConfig, PagedVerifyOutput
+from repro.kernels.paged_decode import paged_besf_pallas
 from repro.kernels.runtime import resolve_interpret
-
-NEG_INF = -1e30
-
-
-def _paged_verify_kernel(
-    # scalar-prefetch (SMEM)
-    tables_ref,             # [B, MB] int32 — logical -> physical page
-    # VMEM-blocked operands
-    lengths_ref,            # [1, Sq] int32 — per-query fill level
-    qpos_ref,               # [1, Sq] int32 — per-query absolute position
-    q_ref,                  # [1, Sq*Hq, D] int32 — quantized draft queries
-    mmin_ref,               # [bits, 1, Sq*Hq] f32 — LATS margin LUT (min)
-    mmax_ref,               # [bits, 1, Sq*Hq] f32 — LATS margin LUT (max)
-    st_ref,                 # [1, Sq*Hq] f32 — scale_total per (query, head)
-    ar_ref,                 # [1, Sq*Hq] f32 — alpha * radius_int
-    vs_ref,                 # [1, Hkv] f32 — V quant scale per KV head
-    # HBM (manually DMA'd) pools
-    kq_hbm,                 # [P, bits, bs8, Hkv, D] uint8 bit-plane pool
-    v_hbm,                  # [P, bs, Hkv, Dv] V pool
-    # outputs
-    out_ref,                # [1, Sq*Hq, Dv]
-    rounds_ref,             # [1, Sq, 1] int32 — planes fetched per query
-    surv_ref,               # [1, Sq*Hq, bs] int8
-    # scratch
-    plane_ref,              # [2, bs8, Hkv, D] uint8 (double buffer)
-    v_ref,                  # [bs, Hkv, Dv]
-    partial_ref,            # [Sq*Hq, bs] int32
-    mlow_ref,               # [Sq*Hq] f32 — LATS prefix max lower bound
-    m_ref, l_ref, acc_ref,  # online softmax state, per (query, head)
-    plane_sem, v_sem,       # DMA semaphores
-    *,
-    bits: int,
-    page_size: int,
-    n_queries: int,
-    n_kv_heads: int,
-    min_rounds: int,
-    quantize_v: bool,
-    window: int | None,
-):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    bs = page_size
-    bs8 = bs // 8
-    Sq = n_queries
-    SH = q_ref.shape[1]                                       # Sq * Hq
-    Hq = SH // Sq
-    D = q_ref.shape[2]
-    G = Hq // n_kv_heads
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        mlow_ref[...] = jnp.full_like(mlow_ref, NEG_INF)
-
-    partial_ref[...] = jnp.zeros_like(partial_ref)
-
-    phys = tables_ref[b, j]
-
-    # Per-query validity of this page's token slots: causal against each
-    # query's own position AND its own fill level (padding queries carry
-    # length 0, making every page dead for them).
-    t_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (Sq, bs), 1)
-    q_pos = qpos_ref[0][:, None]                              # [Sq, 1]
-    length = lengths_ref[0][:, None]
-    valid_q = (t_pos <= q_pos) & (t_pos < length)             # [Sq, bs]
-    if window is not None:
-        valid_q &= t_pos > q_pos - window
-    valid_b = jnp.repeat(valid_q, Hq, axis=0)                 # [Sq*Hq, bs]
-    blk0_q = jnp.any(valid_q, axis=-1)                        # [Sq]
-
-    alpha_radius = ar_ref[0]                                  # [Sq*Hq]
-    qg = q_ref[0].astype(jnp.float32).reshape(Sq, n_kv_heads, G, D)
-
-    def plane_weight(r):
-        mag = jax.lax.shift_left(jnp.int32(1),
-                                 (bits - 1 - r).astype(jnp.int32))
-        return jnp.where(r == 0, -mag, mag)
-
-    def start_plane_copy(r, slot):
-        pltpu.make_async_copy(
-            kq_hbm.at[phys, r], plane_ref.at[slot], plane_sem.at[slot],
-        ).start()
-
-    def wait_plane_copy(slot):
-        pltpu.make_async_copy(
-            kq_hbm.at[0, 0],                       # shape donor only
-            plane_ref.at[slot], plane_sem.at[slot],
-        ).wait()
-
-    # BAP prefetch: plane 0 moves once if ANY query can reach this page.
-    @pl.when(jnp.any(blk0_q))
-    def _prefetch_first():
-        start_plane_copy(0, 0)
-
-    def round_body(r, carry):
-        tok_alive, blk_live_q, rounds_q, mlow = carry
-        slot = jax.lax.rem(r, 2)
-        # Per-query plane accounting: only queries whose page is still
-        # live consumed this plane (the DMA itself is shared).
-        rounds_new = rounds_q + blk_live_q.astype(jnp.int32)
-        blk_live_b = jnp.repeat(blk_live_q, Hq)[:, None]      # [Sq*Hq, 1]
-
-        @pl.when(jnp.any(blk_live_q))
-        def _consume_plane():
-            wait_plane_copy(slot)
-            packed = plane_ref[slot].astype(jnp.int32)        # [bs8, Hkv, D]
-            shifts = jax.lax.broadcasted_iota(
-                jnp.int32, (bs8, 8, n_kv_heads, D), 1)
-            unpacked = (packed[:, None] >> shifts) & 1
-            plane = unpacked.reshape(bs, n_kv_heads, D).astype(jnp.float32)
-            # f32 dot is exact (integers < 2^24); same einsum as the
-            # oracle rows, evaluated once for the whole draft block.
-            delta = jnp.einsum("skgd,tkd->skgt", qg, plane,
-                               preferred_element_type=jnp.float32)
-            # Dead-query over-accumulation is unobservable (see module
-            # docstring) — no per-query gate needed on the partial.
-            partial_ref[...] += (delta.astype(jnp.int32)
-                                 * plane_weight(r)).reshape(SH, bs)
-
-        partial = partial_ref[...].astype(jnp.float32)
-        lower = partial + mmin_ref[r, 0][:, None]
-        upper = partial + mmax_ref[r, 0][:, None]
-        low_here = jnp.max(jnp.where(valid_b & tok_alive, lower, NEG_INF),
-                           axis=-1)
-        mlow_new = jnp.where(blk_live_b[:, 0],
-                             jnp.maximum(mlow, low_here), mlow)
-        eta = mlow_new - alpha_radius
-        keep = tok_alive & (upper >= eta[:, None]) & valid_b
-        keep = jnp.where(r < min_rounds - 1, tok_alive & valid_b, keep)
-        keep = jnp.where(blk_live_b, keep, tok_alive)
-        blk_new_q = jnp.where(
-            blk_live_q,
-            jnp.any(keep.reshape(Sq, Hq, bs), axis=(1, 2)), blk_live_q)
-
-        # BAP: next plane requested as soon as any query still wants it.
-        @pl.when(jnp.any(blk_new_q) & (r + 1 < bits))
-        def _prefetch_next():
-            start_plane_copy(r + 1, 1 - slot)
-
-        return keep, blk_new_q, rounds_new, mlow_new
-
-    tok_alive, _, rounds_q, mlow = jax.lax.fori_loop(
-        0, bits, round_body,
-        (valid_b, blk0_q, jnp.zeros((Sq,), jnp.int32), mlow_ref[...]),
-    )
-    mlow_ref[...] = mlow
-    rounds_ref[0, :, 0] = rounds_q
-
-    survived = tok_alive & jnp.repeat(rounds_q == bits, Hq)[:, None]
-    surv_ref[...] = survived[None].astype(jnp.int8)
-
-    any_surv_q = jnp.any(survived.reshape(Sq, Hq, bs), axis=(1, 2))  # [Sq]
-    any_surv_b = jnp.repeat(any_surv_q, Hq)[:, None]          # [Sq*Hq, 1]
-
-    @pl.when(jnp.any(any_surv_q))
-    def _epilogue():
-        logits = jnp.where(
-            survived,
-            partial_ref[...].astype(jnp.float32) * st_ref[0][:, None],
-            NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-        p = jnp.where(survived, jnp.exp(logits - m_new[:, None]), 0.0)
-        corr = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        l_new = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        # One V DMA serves every query with survivors on this page.
-        cp = pltpu.make_async_copy(v_hbm.at[phys], v_ref, v_sem)
-        cp.start()
-        cp.wait()
-        v = v_ref[...].astype(jnp.float32)
-        if quantize_v:
-            vs = vs_ref[0][None, :, None]
-            v_eff = (qlib.quantize_with_scale(v, vs, bits)
-                     .astype(jnp.float32) * vs)
-        else:
-            v_eff = v
-        upd = jnp.einsum("skgt,tkd->skgd",
-                         p.reshape(Sq, n_kv_heads, G, bs), v_eff,
-                         preferred_element_type=jnp.float32)
-        acc_new = acc_ref[...] * corr[:, None] + upd.reshape(SH, -1)
-        # Each query commits its softmax state only if IT had survivors —
-        # the oracle's where(any_surv, new, old), per query.
-        m_ref[...] = jnp.where(any_surv_b[:, 0], m_new, m_prev)
-        l_ref[...] = jnp.where(any_surv_b[:, 0], l_new, l_ref[...])
-        acc_ref[...] = jnp.where(any_surv_b, acc_new, acc_ref[...])
-
-    @pl.when(j == nj - 1)
-    def _finalize():
-        out_ref[...] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        )[None].astype(out_ref.dtype)
 
 
 @functools.partial(
     jax.jit, static_argnames=("cfg", "window", "interpret", "stats"))
 def paged_bitstopper_verify(
     q: jax.Array,            # [B, Sq, Hq, D] — draft block per slot
-    kq_pool: jax.Array,      # [P, bits, bs//8, Hkv, D] uint8 plane pool
-    v_pool: jax.Array,       # [P, bs, Hkv, Dv] V pool
+    kq_pool: jax.Array,      # [P, bits, R, L] uint8 lane-dense plane pool
+    v_pool: jax.Array,       # [P, bs, Hkv*Dv] (or [P, bs, Hkv, Dv]) V pool
     table: jax.Array,        # [B, MB] int32 block tables
     lengths: jax.Array,      # [B, Sq] int32 per-query fill levels
     q_positions: jax.Array,  # [B, Sq] int32 per-query absolute positions
@@ -269,88 +56,19 @@ def paged_bitstopper_verify(
     Bit-identical observables to ``besf_attention_verify_paged`` (per-query
     plane counts, survivors, V-fetch decisions, attention output) while
     sharing each page's plane/V DMAs across the draft block.
-    ``stats=False`` (the serving hot path) shrinks the survivors store to
-    one page tile per slot and returns ``survivors``/``v_fetched`` as
-    None, like the decode kernel."""
+    ``stats=False`` (the serving hot path) drops the survivor map and
+    returns ``survivors``/``v_fetched`` as None, like the decode kernel."""
     interpret = resolve_interpret(interpret)
-    B, Sq, Hq, D = q.shape
-    P, bits, bs8, Hkv, _ = kq_pool.shape
-    bs = bs8 * 8
-    MB = table.shape[1]
-    Dv = v_pool.shape[-1]
-    SH = Sq * Hq
-    assert bits == cfg.bits and v_pool.shape[1] == bs
-
-    # Shared host-side prep with the oracle: (slot, query) rows flatten to
-    # B*Sq independent Sq=1 decodes as far as quantization is concerned.
-    prep = paged_decode_prep(q.reshape(B * Sq, Hq, D), k_amax, v_amax,
-                             Hkv, cfg)
-    q_int, m_min, m_max, scale_total, alpha_radius, _, v_scale = prep
-    q_int = q_int.reshape(B, SH, D)
-    m_min = m_min.reshape(bits, B, SH)
-    m_max = m_max.reshape(bits, B, SH)
-    scale_total = scale_total.reshape(B, SH)
-    alpha_radius = alpha_radius.reshape(B, SH)
-
-    kernel = functools.partial(
-        _paged_verify_kernel,
-        bits=bits, page_size=bs, n_queries=Sq, n_kv_heads=Hkv,
-        min_rounds=cfg.min_rounds, quantize_v=cfg.quantize_v,
-        window=window,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                    # tables (DMA addressing)
-        grid=(B, MB),
-        in_specs=[
-            pl.BlockSpec((1, Sq), lambda b, j, *_: (b, 0)),      # lengths
-            pl.BlockSpec((1, Sq), lambda b, j, *_: (b, 0)),      # q_pos
-            pl.BlockSpec((1, SH, D), lambda b, j, *_: (b, 0, 0)),  # q_int
-            pl.BlockSpec((bits, 1, SH), lambda b, j, *_: (0, b, 0)),  # m_min
-            pl.BlockSpec((bits, 1, SH), lambda b, j, *_: (0, b, 0)),  # m_max
-            pl.BlockSpec((1, SH), lambda b, j, *_: (b, 0)),      # scale_total
-            pl.BlockSpec((1, SH), lambda b, j, *_: (b, 0)),      # alpha*radius
-            pl.BlockSpec((1, Hkv), lambda b, j, *_: (0, 0)),     # v_scale
-            pl.BlockSpec(memory_space=pl.ANY),                   # kq pool
-            pl.BlockSpec(memory_space=pl.ANY),                   # v pool
-        ],
-        out_specs=[
-            pl.BlockSpec((1, SH, Dv), lambda b, j, *_: (b, 0, 0)),
-            pl.BlockSpec((1, Sq, 1), lambda b, j, *_: (b, 0, j)),
-            pl.BlockSpec((1, SH, bs),
-                         (lambda b, j, *_: (b, 0, j)) if stats else
-                         (lambda b, j, *_: (b, 0, 0))),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, bs8, Hkv, D), jnp.uint8),   # plane double buffer
-            pltpu.VMEM((bs, Hkv, Dv), v_pool.dtype),   # v page
-            pltpu.VMEM((SH, bs), jnp.int32),           # partial scores
-            pltpu.VMEM((SH,), jnp.float32),            # LATS prefix max
-            pltpu.VMEM((SH,), jnp.float32),            # m
-            pltpu.VMEM((SH,), jnp.float32),            # l
-            pltpu.VMEM((SH, Dv), jnp.float32),         # acc
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    out, rounds, surv = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, SH, Dv), jnp.float32),
-            jax.ShapeDtypeStruct((B, Sq, MB), jnp.int32),
-            jax.ShapeDtypeStruct((B, SH, (MB if stats else 1) * bs),
-                                 jnp.int8),
-        ],
-        interpret=interpret,
-    )(table.astype(jnp.int32),
-      lengths.astype(jnp.int32), q_positions.astype(jnp.int32),
-      q_int, m_min, m_max, scale_total, alpha_radius, v_scale[None],
-      kq_pool, v_pool)
-    out = out.reshape(B, Sq, Hq, Dv)
+    B, Sq, Hq, _ = q.shape
+    out, rounds, surv = paged_besf_pallas(
+        q, kq_pool, v_pool, table, lengths, q_positions, k_amax, v_amax,
+        cfg=cfg, window=window, interpret=interpret, stats=stats)
     if not stats:
         return PagedVerifyOutput(out=out, rounds=rounds, survivors=None,
                                  v_fetched=None)
-    survivors = surv.reshape(B, Sq, Hq, MB * bs).astype(bool)
-    v_fetched = survivors.reshape(B, Sq, Hq, MB, bs).any(axis=(2, 4))
+    MB = surv.shape[1]
+    surv = surv.astype(bool).reshape(B, MB, Sq, Hq, -1)
+    survivors = jnp.moveaxis(surv, 1, 3).reshape(B, Sq, Hq, -1)
+    v_fetched = jnp.moveaxis(surv.any(axis=(3, 4)), 1, 2)    # [B, Sq, MB]
     return PagedVerifyOutput(out=out, rounds=rounds, survivors=survivors,
                              v_fetched=v_fetched)

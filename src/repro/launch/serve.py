@@ -3,6 +3,12 @@ sparse attention over a mixed-length request trace.
 
 ``python -m repro.launch.serve --arch stablelm-1.6b --impl bitstopper_xla``
 
+By default the model is the arch's reduced same-family config (d_model 64,
+vocab 256), sized for CPU smoke runs.  ``--full`` builds the published
+config (``configs/registry.py:get_config``) with bf16 parameters and
+activations — the width a chip serves; ``chip_smoke.py`` drives that path
+through :func:`build` and :func:`serve`.
+
 Engines:
 
 * ``--engine paged`` (default) — block-pool KV cache with copy-on-write
@@ -32,14 +38,19 @@ undisturbed run.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Callable
 
 import jax
 import numpy as np
 
-from repro.configs import reduced_config
+from repro.configs import get_config, reduced_config
 from repro.core.besf import BitStopperConfig
+from repro.launch.mesh import make_serve_mesh
 from repro.models import transformer as T
+from repro.models.config import ModelConfig
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import (
     ContinuousBatchingEngine,
     FaultPlan,
@@ -66,9 +77,14 @@ def make_trace(rng, vocab, n_requests, min_len, max_len, new_tokens,
     return reqs
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and cross-validate launcher options (``ap.error`` on misuse)."""
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--arch", default="stablelm-1.6b")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published config at full width and "
+                         "depth with bf16 parameters and activations "
+                         "(default: the reduced CPU smoke config)")
     ap.add_argument("--impl", default="bitstopper_xla",
                     choices=["xla", "bitstopper_xla"])
     ap.add_argument("--engine", default="paged",
@@ -165,9 +181,7 @@ def main():
                          "or @file.json.  Crashes need --snapshot-dir to "
                          "restore from")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
-
-    mesh = None
+    args = ap.parse_args(argv)
     if args.mesh is not None:
         if args.engine != "paged":
             ap.error("--mesh requires --engine paged")
@@ -179,13 +193,59 @@ def main():
         if dp * tp > n_dev:
             ap.error(f"--mesh {dp},{tp} needs {dp * tp} devices, "
                      f"{n_dev} visible")
-        mesh = jax.make_mesh((dp, tp), ("data", "model"))
+    if args.speculative != "off" and args.engine != "paged":
+        ap.error("--speculative requires --engine paged "
+                 "(block-table rollback)")
+    if args.oversubscribe and args.engine != "paged":
+        ap.error("--oversubscribe requires --engine paged "
+                 "(block-pool preemption)")
+    if args.engine != "paged" and (
+            args.swap_host_bytes or args.prefix_host_bytes
+            or args.prefix_store_dir is not None):
+        ap.error("--swap-host-bytes/--prefix-store-dir/--prefix-host-bytes "
+                 "require --engine paged (docs/serving.md)")
+    if args.engine != "paged" and (
+            args.fault_plan is not None or args.snapshot_dir is not None
+            or args.deadline is not None
+            or args.shed_watermark is not None or args.snapshot_every):
+        ap.error("--fault-plan/--snapshot-dir/--snapshot-every/--deadline/"
+                 "--shed-watermark require --engine paged "
+                 "(docs/robustness.md)")
+    return args
 
-    cfg = reduced_config(args.arch).replace(
-        attn_impl=args.impl,
-        bitstopper=BitStopperConfig(alpha=args.alpha),
-    )
-    params = T.init_model(jax.random.PRNGKey(0), cfg)
+
+def model_config(args) -> ModelConfig:
+    """The served model: published widths in bf16 with ``--full``, else
+    the reduced same-family smoke config."""
+    if args.full:
+        cfg = get_config(args.arch).replace(dtype="bfloat16",
+                                            param_dtype="bfloat16")
+    else:
+        cfg = reduced_config(args.arch)
+    return cfg.replace(attn_impl=args.impl,
+                       bitstopper=BitStopperConfig(alpha=args.alpha))
+
+
+@dataclasses.dataclass
+class Deployment:
+    """Everything :func:`serve` needs: the model (random weights from
+    ``--seed``), the engine configuration and the request trace."""
+    cfg: ModelConfig
+    params: object
+    scfg: ServeConfig
+    reqs: list
+    make_engine: Callable
+
+
+def build(args) -> Deployment:
+    """Build the model, engine configuration and trace from parsed
+    options — no engine runs yet."""
+    mesh = None
+    if args.mesh is not None:
+        dp, tp = (int(x) for x in args.mesh.split(","))
+        mesh = make_serve_mesh(dp, tp)
+    cfg = model_config(args)
+    params = T.init_model(jax.random.PRNGKey(args.seed), cfg)
     scfg = ServeConfig(
         max_len=args.shared_prefix + args.max_prompt + args.new_tokens + 8,
         max_slots=args.slots, temperature=args.temperature,
@@ -201,32 +261,6 @@ def main():
         swap_host_bytes=args.swap_host_bytes,
         prefix_store_dir=args.prefix_store_dir,
         prefix_host_bytes=args.prefix_host_bytes)
-    if args.speculative != "off" and args.engine != "paged":
-        ap.error("--speculative requires --engine paged "
-                 "(block-table rollback)")
-    if args.oversubscribe and args.engine != "paged":
-        ap.error("--oversubscribe requires --engine paged "
-                 "(block-pool preemption)")
-    if args.engine != "paged" and (
-            args.swap_host_bytes or args.prefix_host_bytes
-            or args.prefix_store_dir is not None):
-        ap.error("--swap-host-bytes/--prefix-store-dir/--prefix-host-bytes "
-                 "require --engine paged (docs/serving.md)")
-    chaos = args.fault_plan is not None or args.snapshot_dir is not None
-    if args.engine != "paged" and (
-            chaos or args.deadline is not None
-            or args.shed_watermark is not None or args.snapshot_every):
-        ap.error("--fault-plan/--snapshot-dir/--snapshot-every/--deadline/"
-                 "--shed-watermark require --engine paged "
-                 "(docs/robustness.md)")
-    plan = None
-    if args.fault_plan is not None:
-        text = args.fault_plan
-        if text.startswith("@"):
-            with open(text[1:]) as f:
-                text = f.read()
-        plan = FaultPlan.from_json(text)
-
     def make_engine():
         return {"paged": PagedEngine,
                 "continuous": ContinuousBatchingEngine,
@@ -239,11 +273,36 @@ def main():
     if args.besteffort_tail:
         for r in reqs[len(reqs) - args.besteffort_tail:]:
             r.slo = "besteffort"
+    return Deployment(cfg=cfg, params=params, scfg=scfg, reqs=reqs,
+                      make_engine=make_engine)
 
-    if chaos:
+
+def serve(dep: Deployment, seed: int = 0, engine=None):
+    """Serve the deployment's trace to completion — on ``engine`` if given
+    (e.g. one already warmed up), else on a fresh one; returns
+    ``(engine, seconds)``, the engine for its counters."""
+    engine = dep.make_engine() if engine is None else engine
+    t0 = time.monotonic()
+    engine.generate(dep.reqs, seed=seed)
+    return engine, time.monotonic() - t0
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    dep = build(args)
+    reqs = dep.reqs
+    if args.fault_plan is not None or args.snapshot_dir is not None:
+        plan = None
+        if args.fault_plan is not None:
+            text = args.fault_plan
+            if text.startswith("@"):
+                with open(text[1:]) as f:
+                    text = f.read()
+            plan = FaultPlan.from_json(text)
         t0 = time.monotonic()
         reqs, rep = serve_with_chaos(
-            make_engine, reqs, seed=args.seed, plan=plan,
+            dep.make_engine, reqs, seed=args.seed, plan=plan,
             snapshot_dir=args.snapshot_dir)
         dt = time.monotonic() - t0
         n_tok = sum(len(r.generated) for r in reqs)
@@ -267,10 +326,7 @@ def main():
         print(f"[serve] counters: {c}")
         return
 
-    engine = make_engine()
-    t0 = time.monotonic()
-    engine.generate(reqs, seed=args.seed)
-    dt = time.monotonic() - t0
+    engine, dt = serve(dep, seed=args.seed)
     n_tok = sum(len(r.generated) for r in reqs)
     print(f"[serve] {len(reqs)} requests / {n_tok} new tokens in {dt:.2f}s "
           f"({n_tok / dt:.1f} tok/s, engine={args.engine}, impl={args.impl})")

@@ -45,6 +45,7 @@ from repro.configs import reduced_config
 from repro.core.besf import BitStopperConfig
 from repro.models import transformer as T
 from repro.runtime import SystemClock
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import PagedEngine, ServeConfig
 from repro.serving.frontdoor import AsyncFrontDoor, DisaggController, \
     SlaMapper
@@ -207,6 +208,7 @@ def main():
     ap.add_argument("--port", type=int, default=8763)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg, door = build_door(args)
     if args.demo:

@@ -374,9 +374,11 @@ def init_cache(cfg: AttnConfig, batch: int, max_len: int, dtype=jnp.float32,
     re-padding.  ``cache_is_per_slot`` distinguishes the two layouts.
 
     With ``paged=PagedLayout(...)`` the K/V storage loses its batch axis
-    entirely: one ``[pool_blocks, page_size, Hkv, D]`` pool is shared by
-    every slot, addressed through a per-slot block ``table`` (refcounted
-    blocks can appear in several tables — copy-on-write prefix sharing).
+    entirely: one lane-dense ``[pool_blocks, page_size, Hkv*D]`` pool (KV
+    heads merged into the minor dim, so a page is whole (8, 128) tiles on
+    a TPU with no padding of a 64-wide head dim) is shared by every slot,
+    addressed through a per-slot block ``table`` (refcounted blocks can
+    appear in several tables — copy-on-write prefix sharing).
     Sliding-window layers fall back to position masking (no ring): the
     logical index of a token is its absolute position.
 
@@ -385,15 +387,16 @@ def init_cache(cfg: AttnConfig, batch: int, max_len: int, dtype=jnp.float32,
     scales both paged decode paths share — and, when ``cfg.fused_decode``,
     an **incremental bit-plane pool**: ``kq`` holds every page's K rows
     pre-quantized (INT-``bits``) and bit-packed 8 tokens/byte along the
-    page axis — ``uint8[pool_blocks, bits, page_size//8, Hkv, D]`` —
-    written at cache write time so the fused kernel never re-derives
-    planes from the f32 pool (see ``_update_plane_pool`` for the
-    rescale-on-demand rule)."""
+    page axis in the lane-dense row layout of ``qlib.pack_pool_planes`` —
+    ``uint8[pool_blocks, bits, R, Hkv*D/C]`` — written at cache write
+    time so the fused kernel never re-derives planes from the f32 pool
+    (see ``_update_plane_pool`` for the rescale-on-demand rule)."""
     if paged is not None:
         nb, bs = paged.pool_blocks, paged.page_size
+        lanes = cfg.n_kv_heads * cfg.head_dim
         cache = {
-            "k": jnp.zeros((nb, bs, cfg.n_kv_heads, cfg.head_dim), dtype),
-            "v": jnp.zeros((nb, bs, cfg.n_kv_heads, cfg.head_dim), dtype),
+            "k": jnp.zeros((nb, bs, lanes), dtype),
+            "v": jnp.zeros((nb, bs, lanes), dtype),
             "pos": jnp.full((nb, bs), POS_SENTINEL, jnp.int32),
             "table": jnp.zeros((batch, paged.max_blocks_per_req), jnp.int32),
             "length": jnp.zeros((batch,), jnp.int32),
@@ -409,8 +412,7 @@ def init_cache(cfg: AttnConfig, batch: int, max_len: int, dtype=jnp.float32,
                 # don't pay write-time packing/requants it won't use.
                 bits = cfg.bitstopper.bits
                 cache["kq"] = jnp.zeros(
-                    (nb, bits, bs // 8, cfg.n_kv_heads, cfg.head_dim),
-                    jnp.uint8)
+                    qlib.plane_pool_shape(nb, bits, bs, lanes), jnp.uint8)
         return cache
     n_slots = min(max_len, cfg.window) if (ring and cfg.window) else max_len
     if per_slot:
@@ -486,7 +488,6 @@ def _update_cache(cache, k, v, positions):
         cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], vc, widx, 1)
         cpos = jax.lax.dynamic_update_slice_in_dim(cache["pos"], pc, widx, 0)
     else:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         mesh = rules.mesh
         bspec = rules.pspec(("batch",), (cache["k"].shape[0],))[0]
@@ -509,12 +510,12 @@ def _update_cache(cache, k, v, positions):
                 pos_l, jnp.where(in_rng, pn, cur_p), idx, 0)
             return ck_l, cv_l, pos_l
 
-        ck, cv, cpos = shard_map(
+        ck, cv, cpos = jax.shard_map(
             body, mesh=mesh,
             in_specs=(cache_spec, cache_spec, P("model"),
                       new_spec, new_spec, P(None), P(None)),
             out_specs=(cache_spec, cache_spec, P("model")),
-            check_rep=False,
+            check_vma=False,
         )(cache["k"], cache["v"], cache["pos"], kc, vc, pc,
           widx[None])
     new = dict(cache, k=ck, v=cv, pos=cpos, length=cache["length"] + S)
@@ -556,8 +557,9 @@ def _update_plane_pool(cache, kc, vc, real, phys, p_safe, ok, k_pool_new):
     if "kq" not in cache:      # fallback decode: scales only, no packing
         return dict(k_amax=k_amax_new, v_amax=v_amax_new)
     kq = cache["kq"]
-    nb, bits, bs8, H, D = kq.shape
-    bs = bs8 * 8
+    nb, bits = kq.shape[:2]
+    bs = cache["pos"].shape[1]
+    C = qlib.plane_chunks(bs)
     grew = jnp.any(k_amax_new > k_amax)
     k_scale = qlib.scale_from_amax(k_amax_new, bits)          # [H]
 
@@ -575,16 +577,23 @@ def _update_plane_pool(cache, kc, vc, real, phys, p_safe, ok, k_pool_new):
         def write_one(s, kq):
             us = u[:, s]                                      # [B, H, D]
             tokbits = ((us[:, None] >> shifts) & 1).astype(jnp.int32)
+            # The token's byte group is C consecutive rows of every plane.
+            tokbits = qlib.to_plane_rows(tokbits, C)          # [B,bits,C,L]
             off = p_safe[:, s] % bs
             byte, bitpos = off // 8, off % 8                  # [B]
-            row = jnp.where(ok[:, s], phys[:, s], nb)         # OOB => dropped
-            old = kq.at[row, :, byte].get(
-                mode="fill", fill_value=0).astype(jnp.int32)  # [B,bits,H,D]
-            lowmask = ((1 << bitpos) - 1)[:, None, None, None]
-            newbyte = ((old & lowmask)
-                       | (tokbits << bitpos[:, None, None, None]))
-            return kq.at[row, :, byte].set(newbyte.astype(jnp.uint8),
-                                           mode="drop")
+            # One dynamic slice per slot, never a scatter: the pool keeps
+            # the row-major layout the kernel DMAs from.  A dropped token
+            # rewrites its (null) target unchanged.
+            for b in range(u.shape[0]):
+                at = (jnp.where(ok[b, s], phys[b, s], 0), 0, byte[b] * C, 0)
+                old = jax.lax.dynamic_slice(
+                    kq, at, (1, bits, C, kq.shape[3])).astype(jnp.int32)
+                lowmask = (1 << bitpos[b]) - 1
+                new = (old & lowmask) | (tokbits[b] << bitpos[b])
+                new = jnp.where(ok[b, s], new, old)
+                kq = jax.lax.dynamic_update_slice(
+                    kq, new.astype(jnp.uint8), at)
+            return kq
 
         return jax.lax.fori_loop(0, S, write_one, kq)
 
@@ -620,12 +629,12 @@ def _update_paged_cache(cache, k, v, positions):
                                axis=1)                        # [B, S]
     ok = real & (logical < MB) & (phys > 0)
     flat_idx = jnp.where(ok, phys * bs + p_safe % bs, nb * bs)
-    kf = cache["k"].reshape((nb * bs,) + cache["k"].shape[2:])
-    vf = cache["v"].reshape((nb * bs,) + cache["v"].shape[2:])
+    kf = cache["k"].reshape(nb * bs, -1)
+    vf = cache["v"].reshape(nb * bs, -1)
     pf = cache["pos"].reshape(nb * bs)
     fi = flat_idx.reshape(-1)
-    kf = kf.at[fi].set(kc.reshape((-1,) + kc.shape[2:]), mode="drop")
-    vf = vf.at[fi].set(vc.reshape((-1,) + vc.shape[2:]), mode="drop")
+    kf = kf.at[fi].set(kc.reshape(B * S, -1), mode="drop")
+    vf = vf.at[fi].set(vc.reshape(B * S, -1), mode="drop")
     pf = pf.at[fi].set(pc2.reshape(-1), mode="drop")
     new_len = cache["length"] + real.sum(axis=1, dtype=jnp.int32)
     new = dict(cache, k=kf.reshape(cache["k"].shape),
@@ -638,7 +647,8 @@ def _update_paged_cache(cache, k, v, positions):
 
 
 def gather_paged_view(cache, active=None):
-    """Gather each row's dense logical view ``[B, MB*bs]`` from the pool.
+    """Gather each row's dense logical view ``[B, MB*bs]`` from the pool
+    (K/V lane-dense, ``[B, MB*bs, Hkv*D]``, like the pool).
 
     Only the first length[b] view slots were ever written by (or shared
     into) row b, so slots past the fill level are forced invalid and
@@ -658,18 +668,18 @@ def gather_paged_view(cache, active=None):
         table = jnp.where(active[:, None], table, 0)
     B, MB = table.shape
     Tv = MB * bs
-    kf = cache["k"].reshape((nb * bs,) + cache["k"].shape[2:])
-    vf = cache["v"].reshape((nb * bs,) + cache["v"].shape[2:])
+    kf = cache["k"].reshape(nb * bs, -1)
+    vf = cache["v"].reshape(nb * bs, -1)
     pf = cache["pos"].reshape(nb * bs)
     view = (table[..., None] * bs
             + jnp.arange(bs, dtype=jnp.int32)).reshape(B, Tv)
-    k_view = kf[view]                                         # [B, Tv, H, D]
+    k_view = kf[view]                                         # [B, Tv, H*D]
     v_view = vf[view]
     pos_view = pf[view]
     valid = jnp.arange(Tv, dtype=jnp.int32)[None] < cache["length"][:, None]
     pos_view = jnp.where(valid, pos_view, POS_SENTINEL)
-    k_view = jnp.where(valid[..., None, None], k_view, 0)
-    v_view = jnp.where(valid[..., None, None], v_view, 0)
+    k_view = jnp.where(valid[..., None], k_view, 0)
+    v_view = jnp.where(valid[..., None], v_view, 0)
     return k_view, v_view, pos_view
 
 
@@ -707,7 +717,6 @@ def _shard_paged_attention(fn, rules, q, kpool, vpool, table, lengths,
     so the downstream ``wo`` matmul sums in single-device order and the
     output stays bit-identical to the unsharded run.  Slots shard over
     "data" the same way (batch rows are independent)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = rules.mesh
@@ -715,14 +724,15 @@ def _shard_paged_attention(fn, rules, q, kpool, vpool, table, lengths,
     bspec = rules.pspec(("batch",), (q.shape[0],))[0]
     qspec = (P(bspec, None, "model", None) if verify
              else P(bspec, "model", None))
-    kspec = (P(None, None, None, "model", None) if kpool.ndim == 5
-             else P(None, None, "model", None))           # kq vs f32 pool
+    # Lane-dense pools: every KV head owns a contiguous lane range, so a
+    # lane split is a head split (kq [P, bits, R, L], f32 [P, bs, Hkv*D]).
+    kspec = P(*(None,) * (kpool.ndim - 1), "model")
     lspec = P(bspec, None) if verify else P(bspec)
-    out = shard_map(
+    out = jax.shard_map(
         lambda *a: fn(*a).out, mesh=mesh,
-        in_specs=(qspec, kspec, P(None, None, "model", None),
+        in_specs=(qspec, kspec, P(None, None, "model"),
                   P(bspec, None), lspec, lspec, P("model"), P("model")),
-        out_specs=qspec, check_rep=False,
+        out_specs=qspec, check_vma=False,
     )(q, kpool, vpool, table, lengths, q_pos, k_amax, v_amax)
     gspec = (P(bspec, None, None, None) if verify
              else P(bspec, None, None))
@@ -800,6 +810,13 @@ def _paged_cached_attention(q, cache, positions, cfg: AttnConfig):
             out = call(*args).out
         return out[:, None].astype(q.dtype)                   # [B,1,Hq,Dv]
     k_view, v_view, pos_view = gather_paged_view(cache, active)
+    k_view = k_view.reshape(B, -1, cfg.n_kv_heads, cfg.head_dim)
+    v_view = v_view.reshape(B, -1, cfg.n_kv_heads, cfg.head_dim)
+    # Under the serving mesh the pools are KV-head sharded; the gathered
+    # view follows the activations (replicated), so this attention runs at
+    # its single-device shapes — bit-identical to unsharded serving.
+    k_view = constrain(k_view, "batch", "seq", "kv_heads", None)
+    v_view = constrain(v_view, "batch", "seq", "kv_heads", None)
     return _cached_attention(q, k_view, v_view, positions, pos_view, cfg)
 
 
@@ -1126,8 +1143,11 @@ def apply_inject_amax_rule(caches, layers, groups):
             return c
         rows = layers[i]
         stacked = c["table"].ndim == 3
+        H = c["k_amax"].shape[-1]         # lane-dense rows -> heads
         k_rows = np.asarray(rows["k"], np.float32)
+        k_rows = k_rows.reshape(k_rows.shape[:-1] + (H, -1))
         v_rows = np.asarray(rows["v"], np.float32)
+        v_rows = v_rows.reshape(v_rows.shape[:-1] + (H, -1))
         k_amax = np.asarray(c["k_amax"], np.float32).copy()
         v_amax = np.asarray(c["v_amax"], np.float32).copy()
         k0, v0 = k_amax.copy(), v_amax.copy()

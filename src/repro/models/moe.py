@@ -156,7 +156,6 @@ def moe_ffn(p, x, cfg: MoEConfig, mesh=None, ep_axis: str = "model"):
         cap = _capacity(B * S, cfg.top_k, cfg.n_routed, cfg.capacity_factor)
         out2d = _local_moe(p, x2d, top_p, top_i, cfg, 0, cfg.n_routed, cap)
     else:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         dp = tuple(a for a in mesh.axis_names if a != ep_axis)
 
@@ -190,12 +189,12 @@ def moe_ffn(p, x, cfg: MoEConfig, mesh=None, ep_axis: str = "model"):
                                      * mesh.shape["data"] * T_loc)
                 return jax.lax.dynamic_slice_in_dim(y, start, T_loc, 0)
 
-            out2d = shard_map(
+            out2d = jax.shard_map(
                 res_body, mesh=mesh,
                 in_specs=(P(ep_pair), P(ep_pair), P(ep_pair),
                           P(dp), P(dp), P(dp)),
                 out_specs=P(dp),
-                check_rep=False,
+                check_vma=False,
             )(p["wi_gate"], p["wi_up"], p["wo"], x2d, top_p, top_i)
         elif cfg.n_routed % tp == 0:
             # ---- EP: experts sharded over "model".
@@ -209,12 +208,12 @@ def moe_ffn(p, x, cfg: MoEConfig, mesh=None, ep_axis: str = "model"):
                                xs, tpp, tii, cfg, idx * n_local, n_local, cap)
                 return jax.lax.psum(y, ep_axis)
 
-            out2d = shard_map(
+            out2d = jax.shard_map(
                 ep_body, mesh=mesh,
                 in_specs=(P(ep_axis), P(ep_axis), P(ep_axis),
                           P(dp), P(dp), P(dp)),
                 out_specs=P(dp),
-                check_rep=False,
+                check_vma=False,
             )(p["wi_gate"], p["wi_up"], p["wo"], x2d, top_p, top_i)
         else:
             # ---- expert-TP: every shard, all experts, 1/tp of hidden dim.
@@ -225,12 +224,12 @@ def moe_ffn(p, x, cfg: MoEConfig, mesh=None, ep_axis: str = "model"):
                                xs, tpp, tii, cfg, 0, cfg.n_routed, cap)
                 return jax.lax.psum(y, ep_axis)
 
-            out2d = shard_map(
+            out2d = jax.shard_map(
                 tpx_body, mesh=mesh,
                 in_specs=(P(None, None, ep_axis), P(None, None, ep_axis),
                           P(None, ep_axis, None), P(dp), P(dp), P(dp)),
                 out_specs=P(dp),
-                check_rep=False,
+                check_vma=False,
             )(p["wi_gate"], p["wi_up"], p["wo"], x2d, top_p, top_i)
 
     out = out2d.reshape(B, S, D).astype(x.dtype)

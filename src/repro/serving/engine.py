@@ -838,10 +838,20 @@ class PagedEngine(_EngineCommon):
         # paged oracle (the gather fallback) is the fast interpreter-free
         # path.  An explicit ServeConfig.fused_decode bool always wins —
         # fused_decode=True off-TPU runs the kernel in interpret mode,
-        # which is how CI validates it.
+        # which is how CI validates it.  On TPU, auto never settles for the
+        # oracle in silence: a page size the kernel cannot pack is an error
+        # (fused_decode=False asks for the oracle explicitly).
         fused = scfg.fused_decode
         if fused is None:
-            fused = jax.default_backend() == "tpu" and scfg.page_size % 8 == 0
+            fused = jax.default_backend() == "tpu"
+            if (fused and scfg.page_size % 8
+                    and cfg.attn_impl in ("bitstopper", "bitstopper_xla")):
+                raise ValueError(
+                    f"on TPU the paged BitStopper decode runs the fused "
+                    f"kernel, which needs page_size % 8 == 0 (bit planes "
+                    f"pack 8 tokens/byte), got page_size={scfg.page_size}; "
+                    f"pass fused_decode=False to serve through the gather "
+                    f"oracle instead")
         cfg = self.cfg = cfg.replace(fused_decode=bool(fused))
         self.params = params
         self.scfg = scfg
@@ -1354,6 +1364,11 @@ class PagedEngine(_EngineCommon):
         positions = np.full((1, Sp), POS_SENTINEL, np.int32)
         positions[0, :n] = np.arange(s, e, dtype=np.int32)
 
+        # Each step writes a whole new cache (the layer scan rebuilds it),
+        # and a step queued on the device already holds its output: wait
+        # for the previous step so at most two caches are live.  Decode and
+        # verify ticks wait anyway, on the tokens they sample.
+        jax.block_until_ready(self.caches)
         caches = _attach_tables(self.caches, self.table[slot:slot + 1],
                                 self.lengths[slot:slot + 1])
         last_logits, self.caches = self._prefill(
@@ -1999,15 +2014,15 @@ class PagedEngine(_EngineCommon):
         jend = -(-inject_end // bs)
         recs = recs[:jend - m]
         # Merge the per-block records into one extract_block_rows-shaped
-        # layer list (rows axis: 1 for stacked layers, else 0; the pos
-        # plane is 2 ranks slimmer than k/v).
+        # layer list (rows axis: 1 for stacked layers, else 0; k/v rows
+        # are [.., bs, Hkv*D], the pos plane [.., bs]).
         merged = []
         for li in range(len(recs[0])):
             merged.append({
                 f: np.concatenate(
                     [np.asarray(r[li][f]) for r in recs],
                     axis=np.asarray(recs[0][li][f]).ndim
-                    - (2 if f == "pos" else 4))
+                    - (2 if f == "pos" else 3))
                 for f in ("k", "v", "pos")})
         bids = [int(row[j]) for j in range(m, jend)]
         self.caches = splice_block_rows(self.caches, bids, merged)
@@ -2295,15 +2310,8 @@ class PagedEngine(_EngineCommon):
         for i in active:
             assert self.table[i, int(self.lengths[i]) // self._page] != 0, \
                 f"slot {i} reached decode without its next-write block"
-        # Rows still prefilling (or empty) decode at the pad sentinel: their
-        # q/k/v are zeroed and the cache write is dropped.
-        positions = np.full((len(self.slots), 1), POS_SENTINEL, np.int32)
-        for i in active:
-            positions[i, 0] = self.lengths[i]
-        tokens = jnp.asarray(self.last_token[:, None])
-        caches = _attach_tables(self.caches, self.table, self.lengths)
         logits, self.caches = self._guarded_decode(
-            self.params, tokens, caches, jnp.asarray(positions))
+            self.params, *self.decode_inputs(active))
         rids = [st.req.rid if st is not None else 0 for st in self.slots]
         counts = [len(st.req.generated) if st is not None else 0
                   for st in self.slots]
@@ -2319,6 +2327,22 @@ class PagedEngine(_EngineCommon):
             self.lengths[i] += 1
             self.last_token[i] = toks[i]
             self._maybe_evict(i, int(toks[i]))
+
+    def decode_inputs(self, active: list[int] | None = None):
+        """``(tokens, caches, positions)`` of the next plain decode tick
+        over ``active`` rows (default: every prefilled slot) — what the
+        jitted decode forward consumes.  Reads engine state, changes none
+        of it.  Rows still prefilling (or empty) decode at the pad
+        sentinel: their q/k/v are zeroed and the cache write is dropped."""
+        if active is None:
+            active = [i for i, st in enumerate(self.slots)
+                      if st is not None and st.prefilled()]
+        positions = np.full((len(self.slots), 1), POS_SENTINEL, np.int32)
+        for i in active:
+            positions[i, 0] = self.lengths[i]
+        tokens = jnp.asarray(self.last_token[:, None])
+        caches = _attach_tables(self.caches, self.table, self.lengths)
+        return tokens, caches, jnp.asarray(positions)
 
     def _return_draft_blocks(self, slot: int,
                              blocks: list[tuple[int, int, bool]]) -> None:

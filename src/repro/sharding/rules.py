@@ -77,6 +77,8 @@ AXIS_MAP_1POD = {
     "expert": "model",
     "expert_ffn": None,
     "seq_sp": "model",
+    # paged pools (PAGED_CACHE_RULES) shard their KV-head axis
+    "kv_pool": "model",
     # decode KV caches are sharded along the *sequence* axis over "model"
     # (kv-head counts like 8 or 1 don't divide a 16-way axis; sequence
     # always does) — GSPMD turns the softmax/PV over the sharded axis into
@@ -102,17 +104,20 @@ CACHE_RULES: dict[str, tuple] = {
 # some contiguous leaf names ("k", "v", "pos") at *pool* shapes, so they get
 # their own rule table, selected by the presence of the "table" leaf in the
 # same cache dict.  Pools are KV-head-sharded over "model" (per-head BESF
-# attention needs no softmax collectives); the page axis is replicated so the
+# attention needs no softmax collectives): they are lane-dense, each KV head
+# owning a contiguous range of the minor dim, so splitting that dim splits
+# heads — gated on the head count (the ``k_amax`` length) where the layer
+# keeps one, so a head is never cut.  The page axis is replicated so the
 # host-side KVBlockPool allocator stays device-agnostic — one logical block
 # id space, block tables and fill levels replicated across "model".  Slots
 # ("batch") shard over "data".  Indivisible dims (MQA's single KV head on a
 # multi-way model axis) silently replicate via MeshRules.pspec.
 PAGED_CACHE_RULES: dict[str, tuple] = {
-    "k": (None, None, "kv_heads", None),          # [nb, bs, Hkv, D]
-    "v": (None, None, "kv_heads", None),
-    "kq": (None, None, None, "kv_heads", None),   # [nb, bits, bs/8, Hkv, D]
-    "k_amax": ("kv_heads",),                      # [Hkv]
-    "v_amax": ("kv_heads",),
+    "k": (None, None, "kv_pool"),                 # [nb, bs, Hkv*D]
+    "v": (None, None, "kv_pool"),
+    "kq": (None, None, None, "kv_pool"),          # [nb, bits, R, Hkv*D/C]
+    "k_amax": ("kv_pool",),                       # [Hkv]
+    "v_amax": ("kv_pool",),
     "pos": (None, None),                          # [nb, bs] fill levels
     "table": ("batch", None),                     # [slots, MB] block tables
     "length": ("batch",),                         # [slots]
@@ -135,6 +140,11 @@ def cache_pspecs(rules: MeshRules, cache_tree):
     def walk(node):
         if isinstance(node, dict):
             table = PAGED_CACHE_RULES if "table" in node else CACHE_RULES
+            if "table" in node and "k_amax" in node and rules.pspec(
+                    ("kv_pool",), node["k_amax"].shape[-1:])[0] is None:
+                table = {name: tuple(None if a == "kv_pool" else a
+                                     for a in axes)
+                         for name, axes in table.items()}
             out = {}
             for name, sub in node.items():
                 if isinstance(sub, (dict, list, tuple)):
@@ -168,20 +178,23 @@ def cache_shardings(rules: MeshRules, cache_tree):
 
 # Inference-only axis map for the mesh-sharded paged serving engine
 # (ServeConfig.mesh).  Deliberately narrower than the training map: only
-# axes whose sharding is pure data movement are mapped — slots ("batch")
-# over "data", attention heads over "model" (per-head BESF + the paged
-# pools, see PAGED_CACHE_RULES).  Every axis that any float *contraction*
-# runs over (ffn hidden, flattened heads into wo, embed, vocab, kv_seq)
-# stays replicated: sharding a contraction dim makes GSPMD psum partial
-# products, which reassociates float adds and breaks the standing
-# bit-identity invariant (sharded serving == single-device, docs/serving.md).
+# slots ("batch") over "data" and the paged pools' KV heads ("kv_pool")
+# over "model" — the per-head BESF attention reads its local heads inside
+# a shard_map (models/attention.py:_shard_paged_attention).  Every
+# activation stays replicated, so each device runs every matmul at the
+# single-device shape: sharding a contraction dim makes GSPMD psum partial
+# products, and even a head-sharded projection compiles on TPU to a
+# different matmul (a per-device slice of the weight) whose rounding
+# differs — either breaks the standing bit-identity invariant (sharded
+# serving == single-device, docs/serving.md).
 AXIS_MAP_SERVE = {
     "batch": "data",
     "fsdp": None,
     "embed": None,
     "seq": None,
-    "heads": "model",
-    "kv_heads": "model",
+    "heads": None,
+    "kv_heads": None,
+    "kv_pool": "model",
     "heads_flat": None,
     "heads_out": None,
     "ffn": None,
@@ -199,8 +212,9 @@ def make_serve_rules(mesh) -> MeshRules:
 
     Parameters are replicated (``param_rules=()`` — serving has no
     optimizer state to shard, and replicated weights keep every matmul's
-    contraction in single-device summation order); activations shard over
-    slots ("data") and attention heads ("model") only."""
+    contraction in single-device summation order).  Only the paged pools
+    shard over "model" (by KV head, ``kv_pool``); slots shard over "data";
+    every other activation stays replicated (see ``AXIS_MAP_SERVE``)."""
     return MeshRules(mesh=mesh, axis_map=dict(AXIS_MAP_SERVE),
                      param_rules=())
 

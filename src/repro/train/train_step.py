@@ -169,7 +169,6 @@ def allreduce_int8_ef(grads, err_state, mesh, data_axes=("data",)):
     reduce-scattered FSDP shards); the wire format of the cross-replica sum
     becomes int8 + one f32 scale per leaf.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = tuple(a for a in data_axes if a in mesh.shape)
@@ -187,10 +186,10 @@ def allreduce_int8_ef(grads, err_state, mesh, data_axes=("data",)):
         return g_new, e_new
 
     specs = jax.tree_util.tree_map(lambda _: P(), grads)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(specs, specs), out_specs=(specs, specs),
-        check_rep=False,
+        check_vma=False,
     )(grads, err_state)
 
 

@@ -124,6 +124,32 @@ def test_seeded_tile_multiple_violation():
     assert "128" in f.message
 
 
+@pytest.mark.parametrize("block,shape,flagged", [
+    ((1, 128), (4, 128), True),     # one row of a taller array
+    ((8, 1), (8, 4), True),         # one lane of a wider array
+    ((1, 128), (1, 128), False),    # a unit dim spanning the whole array
+])
+def test_seeded_unit_block_dim_not_exempt(block, shape, flagged):
+    """A block dim of 1 in the last two positions is a partial tile —
+    Mosaic refuses it (the old per-(slot, page) ``(1, 1)`` counter block
+    of the paged kernels) — unless it spans the whole array dim."""
+    grid = tuple(s // b for s, b in zip(shape, block))
+
+    def run():
+        pl.pallas_call(
+            _noop_kernel, grid=grid,
+            in_specs=[pl.BlockSpec(block, lambda i, j: (i, j))],
+            out_specs=pl.BlockSpec(shape, lambda i, j: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct(shape, jnp.float32),
+        )(jnp.zeros(shape, jnp.float32))
+
+    findings = kc.check_record(_record_one(run), tile_check=True)
+    if flagged:
+        _here(findings, "kernel-tile-multiple")
+    else:
+        assert "kernel-tile-multiple" not in _rules(findings)
+
+
 def test_seeded_float_scalar_prefetch():
     def run():
         grid_spec = pltpu.PrefetchScalarGridSpec(

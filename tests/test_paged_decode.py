@@ -35,9 +35,16 @@ def _pack_pool(k_pool, k_amax, bits=BITS):
     return qlib.pack_pool_planes(k_pool, k_amax, bits)
 
 
-def _unpack_pool(kq):
-    """uint8[P, bits, bs8, H, D] -> bit planes uint8[bits, P, bs, H, D]."""
-    P, bits, bs8, H, D = kq.shape
+def _unpack_pool(kq, bs, H):
+    """Lane-dense uint8[P, bits, bs8*C, H*X] (row b*C + c holds byte group
+    b's dims c*X .. c*X + X-1 of every head, head-major lanes) -> bit
+    planes uint8[bits, P, bs, H, D]."""
+    P, bits, R, L = kq.shape
+    bs8 = bs // 8
+    C, X = R // bs8, L // H
+    D = C * X
+    kq = kq.reshape(P, bits, bs8, C, H, X).transpose(0, 1, 2, 4, 3, 5)
+    kq = kq.reshape(P, bits, bs8, H, D)
     shifts = jnp.arange(8, dtype=jnp.uint32).reshape(1, 1, 1, 8, 1, 1)
     u = (kq.astype(jnp.uint32)[:, :, :, None] >> shifts) & 1
     return u.reshape(P, bits, bs8 * 8, H, D).astype(jnp.uint8).transpose(
@@ -200,8 +207,8 @@ def _write(cache, k, v, positions):
 def _assert_invariant(cache):
     """Planes stored in kq must equal requantizing the f32 pool under the
     current running scale, for every slot written through any table row."""
-    nb, bits, bs8, H, D = cache["kq"].shape
-    bs = bs8 * 8
+    nb, bs = cache["pos"].shape
+    H = cache["k_amax"].shape[0]
     table = np.asarray(cache["table"])
     length = np.asarray(cache["length"])
     live = np.zeros((nb, bs), bool)
@@ -210,8 +217,9 @@ def _assert_invariant(cache):
             n = int(np.clip(length[b] - j * bs, 0, bs))
             if table[b, j] > 0 and n > 0:
                 live[table[b, j], :n] = True
-    got = np.asarray(_unpack_pool(cache["kq"]))         # [bits, P, bs, H, D]
-    want = np.asarray(_unpack_pool(_pack_pool(cache["k"], cache["k_amax"])))
+    got = np.asarray(_unpack_pool(cache["kq"], bs, H))  # [bits, P, bs, H, D]
+    want = np.asarray(_unpack_pool(_pack_pool(cache["k"], cache["k_amax"]),
+                                   bs, H))
     mask = live[None, :, :, None, None]
     np.testing.assert_array_equal(got * mask, want * mask)
 
@@ -222,7 +230,8 @@ def test_plane_pool_incremental_writes_and_rescale():
     invariant still holds (including previously written tokens)."""
     cfg = _acfg()
     cache = init_cache(cfg, batch=2, max_len=64, paged=PagedLayout(6, 8, 3))
-    assert "kq" in cache and cache["kq"].shape == (6, 12, 1, 2, 8)
+    # page 8: one byte group cut into 8 rows of 2 lanes (1 dim x 2 heads)
+    assert "kq" in cache and cache["kq"].shape == (6, 12, 8, 2)
     cache = dict(cache, table=jnp.asarray([[1, 2, 3], [4, 5, 0]], jnp.int32))
     rng = np.random.default_rng(0)
 

@@ -449,6 +449,24 @@ def test_fused_decode_page_size_validation():
     assert scfg.fused_decode is None
 
 
+@pytest.mark.parametrize("fused_decode", [None, False])
+def test_tpu_auto_fused_decode_never_falls_back_silently(
+        model, monkeypatch, fused_decode):
+    """On TPU, fused_decode=None resolves to the kernel; a page size it
+    cannot pack is an error, never a quiet switch to the gather oracle.
+    fused_decode=False still asks for the oracle explicitly.  The TPU is
+    steered here through the backend query the engine consults."""
+    cfg, params = model
+    cfgb = cfg.replace(attn_impl="bitstopper_xla")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if fused_decode is None:
+        with pytest.raises(ValueError, match="page_size % 8"):
+            _paged(cfgb, params, page_size=12, fused_decode=None)
+    else:
+        eng = _paged(cfgb, params, page_size=12, fused_decode=False)
+        assert eng.cfg.fused_decode is False
+
+
 def test_paged_bitstopper_window_layer_fused():
     """local_attn layers decode through the paged path with window
     masking (position-masked, no ring); fused and fallback must agree

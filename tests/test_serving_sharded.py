@@ -118,7 +118,7 @@ def test_mqa_indivisible_heads_fall_back_replicated():
         eng = check('mqa-fallback', mesh, fused=False, n_kv=1)
         kq = eng.caches['seg0']['b0']['kq']
         for shard in kq.addressable_shards:
-            assert shard.data.shape[-2] == kq.shape[-2], (
+            assert shard.data.shape == kq.shape, (
                 kq.shape, shard.data.shape)
         print('MQA REPLICATED: OK')
     """)
