@@ -776,10 +776,11 @@ def _paged_cached_attention(q, cache, positions, cfg: AttnConfig):
         args = (q, pool, cache["v"], cache["table"], lengths, q_pos,
                 cache["k_amax"], cache["v_amax"])
         rules = _paged_shard_rules(cfg)
-        if rules is not None:
-            out = _shard_paged_attention(call, rules, *args)
-        else:
-            out = call(*args).out
+        with jax.named_scope("attn"):
+            if rules is not None:
+                out = _shard_paged_attention(call, rules, *args)
+            else:
+                out = call(*args).out
         return out.astype(q.dtype)                            # [B,S,Hq,Dv]
     if (cfg.impl in ("bitstopper", "bitstopper_xla") and S == 1
             and "k_amax" in cache):
@@ -804,12 +805,14 @@ def _paged_cached_attention(q, cache, positions, cfg: AttnConfig):
         args = (qt, pool, cache["v"], cache["table"], lengths, q_pos,
                 cache["k_amax"], cache["v_amax"])
         rules = _paged_shard_rules(cfg)
-        if rules is not None:
-            out = _shard_paged_attention(call, rules, *args)
-        else:
-            out = call(*args).out
+        with jax.named_scope("attn"):
+            if rules is not None:
+                out = _shard_paged_attention(call, rules, *args)
+            else:
+                out = call(*args).out
         return out[:, None].astype(q.dtype)                   # [B,1,Hq,Dv]
-    k_view, v_view, pos_view = gather_paged_view(cache, active)
+    with jax.named_scope("kv_gather"):
+        k_view, v_view, pos_view = gather_paged_view(cache, active)
     k_view = k_view.reshape(B, -1, cfg.n_kv_heads, cfg.head_dim)
     v_view = v_view.reshape(B, -1, cfg.n_kv_heads, cfg.head_dim)
     # Under the serving mesh the pools are KV-head sharded; the gathered
@@ -817,7 +820,9 @@ def _paged_cached_attention(q, cache, positions, cfg: AttnConfig):
     # its single-device shapes — bit-identical to unsharded serving.
     k_view = constrain(k_view, "batch", "seq", "kv_heads", None)
     v_view = constrain(v_view, "batch", "seq", "kv_heads", None)
-    return _cached_attention(q, k_view, v_view, positions, pos_view, cfg)
+    with jax.named_scope("attn"):
+        return _cached_attention(q, k_view, v_view, positions, pos_view,
+                                 cfg)
 
 
 def _cached_attention(q, k_all, v_all, q_positions, k_positions,
@@ -939,7 +944,8 @@ def attention(
             )
         new_cache = None
     elif cache_is_paged(cache):
-        new_cache = _update_paged_cache(cache, k, v, positions)
+        with jax.named_scope("kv_write"):
+            new_cache = _update_paged_cache(cache, k, v, positions)
         out = _paged_cached_attention(q, new_cache, positions, cfg)
     else:
         k_all, v_all, k_pos, new_cache = _update_cache(cache, k, v, positions)

@@ -78,7 +78,8 @@ def block_forward(p, x, positions, spec: BlockSpec, cfg: ModelConfig,
             mesh = rules.mesh if rules is not None else None
             y, aux = moe_ffn(p["moe"], h2, cfg.moe_config(), mesh=mesh)
         else:
-            y = L.mlp(p["ffn"], h2, cfg.act)
+            with jax.named_scope("mlp"):
+                y = L.mlp(p["ffn"], h2, cfg.act)
         x = x + y
     # Scan-carry contract: blocks always emit the activation dtype, no
     # matter how param/activation dtypes promoted inside the mixers.
@@ -210,13 +211,15 @@ def forward(params, tokens, cfg: ModelConfig, caches=None,
     S = x.shape[1]
     if positions is None:
         positions = jnp.arange(S)
-    x, new_caches, aux = run_segments(params, x, positions, cfg, caches)
-    h = L.norm(params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = L.unembed(params["embed"], h)
-    else:
-        logits = L.linear(params["unembed"], h).astype(jnp.float32)
-    logits = constrain(logits, "batch", "seq", "vocab")
+    with jax.named_scope("layers"):         # the layer scan
+        x, new_caches, aux = run_segments(params, x, positions, cfg, caches)
+    with jax.named_scope("lm_head"):
+        h = L.norm(params["final_norm"], x)
+        if cfg.tie_embeddings:
+            logits = L.unembed(params["embed"], h)
+        else:
+            logits = L.linear(params["unembed"], h).astype(jnp.float32)
+        logits = constrain(logits, "batch", "seq", "vocab")
     return logits, new_caches, aux
 
 

@@ -53,6 +53,7 @@ from repro.models.attention import (POS_SENTINEL, PagedLayout,
                                     extract_block_rows, repack_block_planes,
                                     requant_plane_pools, splice_block_rows)
 from repro.models.config import ModelConfig
+from repro.runtime.trace import Tracer
 from repro.serving.chaos import CheckpointInterrupted, KernelFault
 from repro.serving.kv_pool import KVBlockPool
 
@@ -466,6 +467,11 @@ def _attach_tables(caches, table: np.ndarray, length: np.ndarray):
 class _EngineCommon:
     """Shared scheduler-loop + measurement surface of the serving engines."""
 
+    # Spans and request stamps (runtime/trace.py).  A tracer without a
+    # clock only annotates the profiler's timeline and keeps no state, so
+    # one instance can be every engine's default.
+    tracer: Tracer = Tracer()
+
     def begin(self, seed: int = 0) -> None:
         """Fix the sampling seed for a serving run.  Split out of
         :meth:`run` so external drivers (``serving/chaos.py``) can own the
@@ -494,11 +500,13 @@ class _EngineCommon:
         return requests
 
     def _sample_rows(self, logits, rids, counts) -> np.ndarray:
-        toks = _sample_tokens(self._base_key, logits,
-                              jnp.asarray(rids, jnp.int32),
-                              jnp.asarray(counts, jnp.int32),
-                              self.scfg.temperature)
-        return np.asarray(toks, np.int32)
+        with self.tracer.span("sample"):
+            toks = _sample_tokens(self._base_key, logits,
+                                  jnp.asarray(rids, jnp.int32),
+                                  jnp.asarray(counts, jnp.int32),
+                                  self.scfg.temperature)
+        with self.tracer.span("readback"):
+            return np.asarray(toks, np.int32)
 
     def _bucketed(self, L: int) -> int:
         b = self.scfg.prefill_bucket
@@ -634,8 +642,7 @@ class ContinuousBatchingEngine(_EngineCommon):
         self._seed = None
         self._base_key = jax.random.PRNGKey(0)
         self.counters = {"prefill_tokens": 0, "decode_tokens": 0,
-                         "decode_steps": 0, "decode_slot_steps": 0,
-                         "requests_finished": 0}
+                         "decode_steps": 0, "requests_finished": 0}
 
     def kv_bytes_resident(self) -> int:
         """KV memory the cache keeps resident: contiguous slots reserve
@@ -716,7 +723,6 @@ class ContinuousBatchingEngine(_EngineCommon):
                   for r in self.slots]
         toks = self._sample_rows(logits, rids, counts)
         self.counters["decode_steps"] += 1
-        self.counters["decode_slot_steps"] += len(self.slots)
         for i in active:
             req = self.slots[i]
             req.generated.append(int(toks[i]))
@@ -831,8 +837,11 @@ class PagedEngine(_EngineCommon):
     non-speculative path itself, so losslessness is unconditional."""
 
     def __init__(self, cfg: ModelConfig, params,
-                 scfg: ServeConfig = ServeConfig(), drafter=None):
+                 scfg: ServeConfig = ServeConfig(), drafter=None,
+                 tracer: Tracer | None = None):
         _supported(cfg)
+        if tracer is not None:
+            self.tracer = tracer
         # Resolve the decode-kernel choice once: the fused paged Pallas
         # kernel wants compiled Pallas (TPU); everywhere else the pure-JAX
         # paged oracle (the gather fallback) is the fast interpreter-free
@@ -964,8 +973,8 @@ class PagedEngine(_EngineCommon):
         self.requests: dict[int, Request] = {}
         self.counters = {"prefill_tokens": 0, "prefix_hit_tokens": 0,
                          "prefill_chunks": 0, "decode_tokens": 0,
-                         "decode_steps": 0, "decode_slot_steps": 0,
-                         "decode_kv_tokens": 0, "requests_finished": 0,
+                         "decode_steps": 0, "decode_kv_tokens": 0,
+                         "requests_finished": 0,
                          "spec_ticks": 0, "spec_proposed": 0,
                          "spec_accepted": 0, "spec_bailouts": 0,
                          "preemptions": 0, "preempt_freed_blocks": 0,
@@ -1207,6 +1216,7 @@ class PagedEngine(_EngineCommon):
         self._register(req)
         req.submitted_tick = self.ticks
         self.queue.append(req)
+        self.tracer.event(req.rid, "submit")
         return req
 
     def _deadline_of(self, req: Request) -> int | None:
@@ -1330,6 +1340,7 @@ class PagedEngine(_EngineCommon):
                 blocks_reserved=goal - n_ctx,
                 ctx=ctx, resumed=resumed, seq=self._admit_seq)
             self._admit_seq += 1
+            self.tracer.event(req.rid, "admit")
             if cached_len < Lc:
                 self._prefill_fifo.append(slot)
             else:
@@ -1350,6 +1361,16 @@ class PagedEngine(_EngineCommon):
         recompute of the unshared tail is just more chunked prefill."""
         if not self._prefill_fifo:
             return
+        # Each step writes a whole new cache (the layer scan rebuilds it),
+        # and a step queued on the device already holds its output: wait
+        # for the previous step so at most two caches are live.  Decode and
+        # verify ticks wait anyway, on the tokens they sample.
+        with self.tracer.span("prefill_wait"):
+            jax.block_until_ready(self.caches)
+        with self.tracer.span("prefill"):
+            self._prefill_chunk()
+
+    def _prefill_chunk(self) -> None:
         slot = self._prefill_fifo[0]
         st = self.slots[slot]
         req = st.req
@@ -1363,12 +1384,6 @@ class PagedEngine(_EngineCommon):
         tokens[0, :n] = np.asarray(st.ctx[s:e], np.int32)
         positions = np.full((1, Sp), POS_SENTINEL, np.int32)
         positions[0, :n] = np.arange(s, e, dtype=np.int32)
-
-        # Each step writes a whole new cache (the layer scan rebuilds it),
-        # and a step queued on the device already holds its output: wait
-        # for the previous step so at most two caches are live.  Decode and
-        # verify ticks wait anyway, on the tokens they sample.
-        jax.block_until_ready(self.caches)
         caches = _attach_tables(self.caches, self.table[slot:slot + 1],
                                 self.lengths[slot:slot + 1])
         last_logits, self.caches = self._prefill(
@@ -1399,9 +1414,11 @@ class PagedEngine(_EngineCommon):
                 self.last_token[slot] = int(req.generated[-1])
             else:
                 tok = int(self._sample_rows(last_logits, [req.rid], [0])[0])
-                req.generated.append(tok)
-                self.last_token[slot] = tok
-                self._maybe_evict(slot, tok)
+                with self.tracer.span("emit"):
+                    req.generated.append(tok)
+                    self.tracer.event(req.rid, "first_token")
+                    self.last_token[slot] = tok
+                    self._maybe_evict(slot, tok)
 
     def _maybe_evict(self, slot: int, tok: int) -> None:
         st = self.slots[slot]
@@ -1436,11 +1453,16 @@ class PagedEngine(_EngineCommon):
         """One scheduler tick: admit, one prefill chunk, one decode step
         (plain or speculative) over every prefilled slot.  Returns False
         when there is no work."""
+        with self.tracer.span("step"):
+            return self._tick()
+
+    def _tick(self) -> bool:
         # The tick counter is the engine's only clock: fault injection,
         # deadlines, and snapshot cadence all key on it (wall clock is
         # lint-banned from serving/ — repo-tick-wallclock).
         self.ticks += 1
-        self._admit()
+        with self.tracer.span("admit"):
+            self._admit()
         self._prefill_tick()
         active = [i for i, st in enumerate(self.slots)
                   if st is not None and st.prefilled()]
@@ -1454,13 +1476,14 @@ class PagedEngine(_EngineCommon):
         # out of `active` (it is requeued, not lost).  Mandatory claims
         # happen here, before any speculative drafting, so a spec tick
         # never preempts for optional draft blocks.
-        for i in active:
-            st = self.slots[i]
-            if st is None or not st.prefilled():
-                continue                      # preempted by an earlier claim
-            j = int(self.lengths[i]) // self._page
-            if self.table[i, j] == 0:
-                self._claim_block(i, j)
+        with self.tracer.span("decode"):
+            for i in active:
+                st = self.slots[i]
+                if st is None or not st.prefilled():
+                    continue                  # preempted by an earlier claim
+                j = int(self.lengths[i]) // self._page
+                if self.table[i, j] == 0:
+                    self._claim_block(i, j)
         active = [i for i in active if self.slots[i] is not None
                   and self.slots[i].prefilled()]
         if not active:
@@ -2310,23 +2333,24 @@ class PagedEngine(_EngineCommon):
         for i in active:
             assert self.table[i, int(self.lengths[i]) // self._page] != 0, \
                 f"slot {i} reached decode without its next-write block"
-        logits, self.caches = self._guarded_decode(
-            self.params, *self.decode_inputs(active))
+        with self.tracer.span("decode"):
+            logits, self.caches = self._guarded_decode(
+                self.params, *self.decode_inputs(active))
         rids = [st.req.rid if st is not None else 0 for st in self.slots]
         counts = [len(st.req.generated) if st is not None else 0
                   for st in self.slots]
         toks = self._sample_rows(logits, rids, counts)
         self.counters["decode_steps"] += 1
-        self.counters["decode_slot_steps"] += len(self.slots)
         self.counters["decode_kv_tokens"] += sum(
             int(self.lengths[i]) + 1 for i in active)
-        for i in active:
-            req = self.slots[i].req
-            req.generated.append(int(toks[i]))
-            self.counters["decode_tokens"] += 1
-            self.lengths[i] += 1
-            self.last_token[i] = toks[i]
-            self._maybe_evict(i, int(toks[i]))
+        with self.tracer.span("emit"):
+            for i in active:
+                req = self.slots[i].req
+                req.generated.append(int(toks[i]))
+                self.counters["decode_tokens"] += 1
+                self.lengths[i] += 1
+                self.last_token[i] = toks[i]
+                self._maybe_evict(i, int(toks[i]))
 
     def decode_inputs(self, active: list[int] | None = None):
         """``(tokens, caches, positions)`` of the next plain decode tick
@@ -2343,6 +2367,25 @@ class PagedEngine(_EngineCommon):
         tokens = jnp.asarray(self.last_token[:, None])
         caches = _attach_tables(self.caches, self.table, self.lengths)
         return tokens, caches, jnp.asarray(positions)
+
+    def programs(self) -> dict:
+        """The jitted forwards this engine serves with, each with
+        arguments of a shape it runs it at: ``{"decode_fn": (jitted,
+        args), "prefill_fn@<width>": ...}``, one prefill entry per prompt
+        chunk width.  ``jitted.lower(*args).compile()`` gives the program
+        the device runs (from the compile cache once served), whose HLO
+        names the ops of a profile and their ``named_scope``s."""
+        out = {"decode_fn": (self._decode,
+                             (self.params, *self.decode_inputs()))}
+        caches = _attach_tables(self.caches, self.table[:1],
+                                self.lengths[:1])
+        b = self.scfg.prefill_bucket
+        for w in range(b, self._chunk + 1, b):
+            zeros = jnp.zeros((1, w), jnp.int32)
+            out[f"prefill_fn@{w}"] = (self._prefill, (
+                self.params, zeros, caches, zeros,
+                jnp.asarray(0, jnp.int32)))
+        return out
 
     def _return_draft_blocks(self, slot: int,
                              blocks: list[tuple[int, int, bool]]) -> None:
@@ -2492,7 +2535,6 @@ class PagedEngine(_EngineCommon):
                                  rids.reshape(-1),
                                  counts.reshape(-1)).reshape(B, Sq)
         self.counters["decode_steps"] += 1
-        self.counters["decode_slot_steps"] += len(self.slots)
         self.counters["decode_kv_tokens"] += sum(
             int(self.lengths[i]) + 1 + len(drafts[i]) for i in active)
 
