@@ -16,9 +16,13 @@ the *physical* pages directly; no dense per-slot view is materialized:
 * **Paging via scalar prefetch.**  Block tables ride in SMEM
   (``PrefetchScalarGridSpec``), so the kernel computes every DMA address
   itself: grid ``(slot, kv_page)`` with the page axis innermost and
-  sequential.  A page past every query's fill level issues **no DMA at
-  all** — per-step traffic scales with actual fill, not with the padded
-  table width.
+  sequential.  A page outside every query's reach runs **nothing** — no
+  DMA and no compute: the wrapper passes each slot's page range
+  ``[lo, hi)`` (:func:`page_bounds`, from the same fill levels, positions
+  and window the kernel masks with) as two more scalar-prefetch operands,
+  and the scalar core skips the whole per-page body of a grid step
+  outside it.  Per-step traffic and time scale with actual fill, not with
+  the padded table width; only the grid step's own overhead remains.
 * **BESF at page granularity.**  K lives pre-quantized in the incremental
   bit-plane pool (``uint8[pool_blocks, bits, R, Hkv*D/C]``, packed 8
   tokens/byte at cache-write time under the pool-wide running per-KV-head
@@ -66,9 +70,11 @@ dim.  So the plane matmul runs over all KV heads' lanes at once against a
 head's lanes), and the V matmul yields every KV head's lanes per row, of
 which the wrapper keeps the row's own head.
 
-Numerics are exact: plane matmuls are f32 (every intermediate an integer
-< 2^24, the zero lanes add exact zeros) accumulated into an int32
-partial-score scratch.  The pure-JAX
+Numerics are exact: the INT12 query rides as two bf16 halves
+(``q = 64 * hi + lo``, ``|hi| <= 32``, ``0 <= lo < 64``, both exact in
+bf16), so each plane matmul is two one-pass bf16 MXU dots whose f32 sums
+are integers < 2^24 (the zero lanes add exact zeros), accumulated into an
+int32 partial-score scratch.  The pure-JAX
 oracles this kernel must match bit for bit are
 ``core/besf.py:besf_attention_decode_paged`` / ``besf_attention_verify_
 paged`` — same page order, same online-softmax op order, same pool-wide
@@ -95,10 +101,13 @@ NEG_INF = -1e30
 def _paged_besf_kernel(
     # scalar-prefetch (SMEM)
     tables_ref,             # [B, MB] int32 — logical -> physical page
+    lo_ref,                 # [B] int32 — first page any query reaches
+    hi_ref,                 # [B] int32 — one past the last such page
     # VMEM-blocked operands; rows are (query, head) pairs, row = s*Hq + h
     qpos_ref,               # [1, SH, 1] int32 — absolute query position
     len_ref,                # [1, SH, 1] int32 — query's fill level
-    q_ref,                  # [1, SH, C*L] int32 — block-diagonal queries
+    q_ref,                  # [1, 2, SH, C*L] bf16 — block-diagonal
+                            # queries as q = 64 * q[0] + q[1]
     mmin_ref,               # [1, SH, bits] f32 — LATS margin LUT (min)
     mmax_ref,               # [1, SH, bits] f32 — LATS margin LUT (max)
     st_ref,                 # [1, SH, 1] f32 — scale_total
@@ -133,7 +142,7 @@ def _paged_besf_kernel(
     bs8 = bs // 8
     Sq = n_queries
     C = chunks
-    SH = q_ref.shape[1]
+    SH = q_ref.shape[2]
     L = kq_hbm.shape[3]
     Hq = SH // Sq
 
@@ -145,161 +154,197 @@ def _paged_besf_kernel(
         mlow_ref[...] = jnp.full_like(mlow_ref, NEG_INF)
         rounds_ref[...] = jnp.zeros_like(rounds_ref)
 
-    partial_ref[...] = jnp.zeros_like(partial_ref)
+    # A page outside [lo, hi) holds no token any query of this slot can
+    # reach: the step runs nothing — no DMA, no plane rounds — and leaves
+    # every output as _init made it (its plane count lane stays 0).
+    reached = (j >= lo_ref[b]) & (j < hi_ref[b])
 
-    phys = tables_ref[b, j]
+    @pl.when(reached)
+    def _page():
+        partial_ref[...] = jnp.zeros_like(partial_ref)
 
-    # Per-row validity of this page's token slots: causal against the
-    # query's own position AND its own fill level (padding queries carry
-    # length 0, making every page dead for them).
-    t_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (SH, bs), 1)
-    q_pos = qpos_ref[0]                                       # [SH, 1]
-    valid_b = (t_pos <= q_pos) & (t_pos < len_ref[0])         # [SH, bs]
-    if window is not None:
-        valid_b &= t_pos > q_pos - window
+        phys = tables_ref[b, j]
 
-    def per_query(x):
-        """[SH, n] bool -> [SH, 1] bool: any over the query's rows."""
-        row = jnp.max(x.astype(jnp.int32), axis=1, keepdims=True)
-        grp = jnp.max(row.reshape(Sq, Hq, 1), axis=1, keepdims=True)
-        return jnp.broadcast_to(grp, (Sq, Hq, 1)).reshape(SH, 1) > 0
+        # Per-row validity of this page's token slots: causal against the
+        # query's own position AND its own fill level (padding queries carry
+        # length 0, making every page dead for them).
+        t_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (SH, bs), 1)
+        q_pos = qpos_ref[0]                                       # [SH, 1]
+        valid_b = (t_pos <= q_pos) & (t_pos < len_ref[0])         # [SH, bs]
+        if window is not None:
+            valid_b &= t_pos > q_pos - window
 
-    blk0 = per_query(valid_b)                                 # [SH, 1]
-    alpha_radius = ar_ref[0]                                  # [SH, 1]
-    q_f = q_ref[0].astype(jnp.float32)                        # [SH, C*L]
-    shifts = jax.lax.broadcasted_iota(jnp.int32, (8, L), 0)
-    lane_r = jax.lax.broadcasted_iota(jnp.int32, (SH, bits), 1)
+        def per_query(x):
+            """[SH, n] bool -> [SH, 1] bool: any over the query's rows."""
+            row = jnp.max(x.astype(jnp.int32), axis=1, keepdims=True)
+            grp = jnp.max(row.reshape(Sq, Hq, 1), axis=1, keepdims=True)
+            return jnp.broadcast_to(grp, (Sq, Hq, 1)).reshape(SH, 1) > 0
 
-    def plane_weight(r):
-        mag = jax.lax.shift_left(jnp.int32(1), (bits - 1 - r).astype(jnp.int32))
-        return jnp.where(r == 0, -mag, mag)
+        blk0 = per_query(valid_b)                                 # [SH, 1]
+        alpha_radius = ar_ref[0]                                  # [SH, 1]
+        q_hi, q_lo = q_ref[0, 0], q_ref[0, 1]                     # [SH, C*L]
+        shifts = jax.lax.broadcasted_iota(jnp.int32, (8, L), 0)
+        lane_r = jax.lax.broadcasted_iota(jnp.int32, (SH, bits), 1)
 
-    def margin(lut_ref, r):
-        # Lane r of the [SH, bits] LUT as a [SH, 1] column (exact: one
-        # term plus zeros).
-        return jnp.sum(jnp.where(lane_r == r, lut_ref[0], 0.0), axis=1,
-                       keepdims=True)
+        def plane_weight(r):
+            mag = jax.lax.shift_left(jnp.int32(1),
+                                     (bits - 1 - r).astype(jnp.int32))
+            return jnp.where(r == 0, -mag, mag)
 
-    def start_plane_copy(r, slot):
-        pltpu.make_async_copy(
-            kq_hbm.at[phys, r], plane_ref.at[slot], plane_sem.at[slot],
-        ).start()
+        def margin(lut_ref, r):
+            # Lane r of the [SH, bits] LUT as a [SH, 1] column (exact: one
+            # term plus zeros).
+            return jnp.sum(jnp.where(lane_r == r, lut_ref[0], 0.0), axis=1,
+                           keepdims=True)
 
-    def wait_plane_copy(slot):
-        pltpu.make_async_copy(
-            kq_hbm.at[0, 0],                       # shape donor only
-            plane_ref.at[slot], plane_sem.at[slot],
-        ).wait()
+        def start_plane_copy(r, slot):
+            pltpu.make_async_copy(
+                kq_hbm.at[phys, r], plane_ref.at[slot], plane_sem.at[slot],
+            ).start()
 
-    # BAP prefetch: plane 0 moves once if ANY query can reach this page.
-    @pl.when(jnp.any(blk0))
-    def _prefetch_first():
-        start_plane_copy(0, 0)
+        def wait_plane_copy(slot):
+            pltpu.make_async_copy(
+                kq_hbm.at[0, 0],                       # shape donor only
+                plane_ref.at[slot], plane_sem.at[slot],
+            ).wait()
 
-    def round_body(r, carry):
-        # Liveness rides the loop carry as int32 (Mosaic carries no i1
-        # vectors through scf.for).
-        tok_alive, blk_live, rounds, mlow = carry
-        tok_alive, blk_live = tok_alive > 0, blk_live > 0
-        slot = jax.lax.rem(r, 2)
-        # Per-query plane accounting: only queries whose page is still
-        # live consumed this plane (the DMA itself is shared).
-        rounds_new = rounds + blk_live.astype(jnp.int32)
+        # BAP prefetch: plane 0 moves once if ANY query can reach this page.
+        @pl.when(jnp.any(blk0))
+        def _prefetch_first():
+            start_plane_copy(0, 0)
 
-        @pl.when(jnp.any(blk_live))
-        def _consume_plane():
-            wait_plane_copy(slot)
-            packed = plane_ref[slot].astype(jnp.int32)        # [R, L]
-            # Row b*C + c holds byte group b's head-dim chunk c: unpacked,
-            # byte groups stack into the chunk's [bs, L] 0/1 plane.  Each
-            # chunk's f32 dot is exact — every partial product is an
-            # integer bounded by 2048 * D < 2^24 — at full f32 contract
-            # precision (a bf16 pass would round the 12-bit query).
-            delta = 0
-            for c in range(C):
-                plane = jnp.concatenate(
-                    [(packed[b * C + c:b * C + c + 1] >> shifts) & 1
-                     for b in range(bs8)], axis=0).astype(jnp.float32)
-                delta = delta + jax.lax.dot_general(
-                    q_f[:, c * L:(c + 1) * L], plane,
-                    (((1,), (1,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32)       # [SH, bs]
-            partial_ref[...] += delta.astype(jnp.int32) * plane_weight(r)
+        def round_body(r, carry):
+            # Liveness rides the loop carry as int32 (Mosaic carries no i1
+            # vectors through scf.for).
+            tok_alive, blk_live, rounds, mlow = carry
+            tok_alive, blk_live = tok_alive > 0, blk_live > 0
+            slot = jax.lax.rem(r, 2)
+            # Per-query plane accounting: only queries whose page is still
+            # live consumed this plane (the DMA itself is shared).
+            rounds_new = rounds + blk_live.astype(jnp.int32)
 
-        partial = partial_ref[...].astype(jnp.float32)
-        lower = partial + margin(mmin_ref, r)
-        upper = partial + margin(mmax_ref, r)
-        low_here = jnp.max(jnp.where(valid_b & tok_alive, lower, NEG_INF),
-                           axis=1, keepdims=True)
-        mlow_new = jnp.where(blk_live, jnp.maximum(mlow, low_here), mlow)
-        eta = mlow_new - alpha_radius
-        # Boolean algebra, not jnp.where: Mosaic cannot select between i1
-        # vectors.  Rounds before min_rounds keep every valid candidate; a
-        # dead page's liveness stays frozen.
-        keep = tok_alive & valid_b & ((upper >= eta) | (r < min_rounds - 1))
-        keep = (blk_live & keep) | (~blk_live & tok_alive)
-        blk_new = blk_live & per_query(keep)
+            @pl.when(jnp.any(blk_live))
+            def _consume_plane():
+                wait_plane_copy(slot)
+                packed = plane_ref[slot].astype(jnp.int32)        # [R, L]
+                # Row b*C + c holds byte group b's head-dim chunk c: unpacked,
+                # byte groups stack into the chunk's [bs, L] 0/1 plane.  The
+                # 12-bit query rides as two bf16 halves, each exact in bf16
+                # (|q_hi| <= 32, 0 <= q_lo < 64), so each is one MXU pass
+                # whose f32 sum of integers is exact, and 64 * hi + lo is the
+                # exact integer score.
+                delta = 0
+                for c in range(C):
+                    plane = jnp.concatenate(
+                        [(packed[b * C + c:b * C + c + 1] >> shifts) & 1
+                         for b in range(bs8)], axis=0
+                    ).astype(jnp.float32).astype(jnp.bfloat16)
 
-        # BAP: next plane requested as soon as any query still wants it.
-        @pl.when(jnp.any(blk_new) & (r + 1 < bits))
-        def _prefetch_next():
-            start_plane_copy(r + 1, 1 - slot)
+                    def dot(x):
+                        return jax.lax.dot_general(
+                            x[:, c * L:(c + 1) * L], plane,
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)   # [SH, bs]
 
-        return (keep.astype(jnp.int32), blk_new.astype(jnp.int32),
-                rounds_new, mlow_new)
+                    delta = delta + (dot(q_hi) * 64 + dot(q_lo))
+                partial_ref[...] += delta.astype(jnp.int32) * plane_weight(r)
 
-    tok_alive, _, rounds, mlow = jax.lax.fori_loop(
-        0, bits, round_body,
-        (valid_b.astype(jnp.int32), blk0.astype(jnp.int32),
-         jnp.zeros((SH, 1), jnp.int32), mlow_ref[...]),
-    )
-    mlow_ref[...] = mlow
-    rounds_q = jnp.max(rounds.reshape(Sq, Hq, 1), axis=1)     # [Sq, 1]
-    lane_j = jax.lax.broadcasted_iota(jnp.int32, rounds_ref.shape[1:], 1)
-    rounds_ref[0] = jnp.where(lane_j == j, rounds_q, rounds_ref[0])
+            partial = partial_ref[...].astype(jnp.float32)
+            lower = partial + margin(mmin_ref, r)
+            upper = partial + margin(mmax_ref, r)
+            low_here = jnp.max(jnp.where(valid_b & tok_alive, lower, NEG_INF),
+                               axis=1, keepdims=True)
+            mlow_new = jnp.where(blk_live, jnp.maximum(mlow, low_here), mlow)
+            eta = mlow_new - alpha_radius
+            # Boolean algebra, not jnp.where: Mosaic cannot select between i1
+            # vectors.  Rounds before min_rounds keep every valid candidate; a
+            # dead page's liveness stays frozen.
+            keep = tok_alive & valid_b & ((upper >= eta)
+                                          | (r < min_rounds - 1))
+            keep = (blk_live & keep) | (~blk_live & tok_alive)
+            blk_new = blk_live & per_query(keep)
 
-    survived = (tok_alive > 0) & (rounds == bits)             # [SH, bs]
+            # BAP: next plane requested as soon as any query still wants it.
+            @pl.when(jnp.any(blk_new) & (r + 1 < bits))
+            def _prefetch_next():
+                start_plane_copy(r + 1, 1 - slot)
+
+            return (keep.astype(jnp.int32), blk_new.astype(jnp.int32),
+                    rounds_new, mlow_new)
+
+        tok_alive, _, rounds, mlow = jax.lax.fori_loop(
+            0, bits, round_body,
+            (valid_b.astype(jnp.int32), blk0.astype(jnp.int32),
+             jnp.zeros((SH, 1), jnp.int32), mlow_ref[...]),
+        )
+        mlow_ref[...] = mlow
+        rounds_q = jnp.max(rounds.reshape(Sq, Hq, 1), axis=1)     # [Sq, 1]
+        lane_j = jax.lax.broadcasted_iota(jnp.int32, rounds_ref.shape[1:], 1)
+        rounds_ref[0] = jnp.where(lane_j == j, rounds_q, rounds_ref[0])
+
+        survived = (tok_alive > 0) & (rounds == bits)             # [SH, bs]
+        if stats:
+            surv_ref[0, 0] = survived.astype(jnp.int8)
+        any_surv = per_query(survived)                            # [SH, 1]
+
+        @pl.when(jnp.any(any_surv))
+        def _epilogue():
+            logits = jnp.where(
+                survived, partial_ref[...].astype(jnp.float32) * st_ref[0],
+                NEG_INF)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
+            p = jnp.where(survived, jnp.exp(logits - m_new), 0.0)
+            corr = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
+            l_new = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+            # One V DMA serves every query with survivors on this page.
+            cp = pltpu.make_async_copy(v_hbm.at[phys], v_ref, v_sem)
+            cp.start()
+            cp.wait()
+            v = v_ref[...].astype(jnp.float32)                # [bs, Hkv*Dv]
+            if quantize_v:
+                vs = vs_ref[...]
+                v_eff = (qlib.quantize_with_scale(v, vs, bits)
+                         .astype(jnp.float32) * vs)
+            else:
+                v_eff = v
+            # Every row against every KV head's V lanes: row (s, h) is right
+            # in its own head's lanes, and the wrapper keeps only those.
+            upd = jnp.dot(p, v_eff, precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)
+            acc_new = acc_ref[...] * corr + upd
+            # Each query commits its softmax state only if IT had survivors —
+            # the oracle's where(any_surv, new, old), per query.
+            m_ref[...] = jnp.where(any_surv, m_new, m_prev)
+            l_ref[...] = jnp.where(any_surv, l_new, l_ref[...])
+            acc_ref[...] = jnp.where(any_surv, acc_new, acc_ref[...])
+
     if stats:
-        surv_ref[0, 0] = survived.astype(jnp.int8)
-    any_surv = per_query(survived)                            # [SH, 1]
-
-    @pl.when(jnp.any(any_surv))
-    def _epilogue():
-        logits = jnp.where(
-            survived, partial_ref[...].astype(jnp.float32) * st_ref[0],
-            NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
-        p = jnp.where(survived, jnp.exp(logits - m_new), 0.0)
-        corr = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        l_new = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        # One V DMA serves every query with survivors on this page.
-        cp = pltpu.make_async_copy(v_hbm.at[phys], v_ref, v_sem)
-        cp.start()
-        cp.wait()
-        v = v_ref[...].astype(jnp.float32)                    # [bs, Hkv*Dv]
-        if quantize_v:
-            vs = vs_ref[...]
-            v_eff = (qlib.quantize_with_scale(v, vs, bits)
-                     .astype(jnp.float32) * vs)
-        else:
-            v_eff = v
-        # Every row against every KV head's V lanes: row (s, h) is right
-        # in its own head's lanes, and the wrapper keeps only those.
-        upd = jnp.dot(p, v_eff, precision=jax.lax.Precision.HIGHEST,
-                      preferred_element_type=jnp.float32)
-        acc_new = acc_ref[...] * corr + upd
-        # Each query commits its softmax state only if IT had survivors —
-        # the oracle's where(any_surv, new, old), per query.
-        m_ref[...] = jnp.where(any_surv, m_new, m_prev)
-        l_ref[...] = jnp.where(any_surv, l_new, l_ref[...])
-        acc_ref[...] = jnp.where(any_surv, acc_new, acc_ref[...])
+        @pl.when(jnp.logical_not(reached))
+        def _no_survivors():
+            surv_ref[...] = jnp.zeros_like(surv_ref)
 
     @pl.when(j == nj - 1)
     def _finalize():
         out_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                       ).astype(out_ref.dtype)
+
+
+def page_bounds(lengths, q_positions, page_size: int,
+                window: int | None = None, xp=jnp):
+    """Per-slot page range ``[lo, hi)`` holding every token some query of
+    the slot attends, from the same bounds the kernel masks with: query
+    ``s`` sees tokens ``max(0, pos - window + 1) <= t < min(len, pos + 1)``.
+    ``lengths`` / ``q_positions`` are ``[B, Sq]``; a slot no query reaches
+    (empty, still prefilling, padding only) gets ``lo == hi == 0``.
+    ``xp`` is ``jnp`` inside the kernel wrapper, ``np`` on the host."""
+    hi_tok = xp.minimum(lengths, q_positions + 1)
+    lo_tok = (xp.zeros_like(hi_tok) if window is None
+              else xp.maximum(q_positions - window + 1, 0))
+    live = hi_tok > lo_tok
+    hi = xp.where(live, -(-hi_tok // page_size), 0).max(axis=-1)
+    lo = xp.where(live, lo_tok // page_size, hi[..., None]).min(axis=-1)
+    return lo.astype(xp.int32), hi.astype(xp.int32)
 
 
 def paged_besf_pallas(q, kq_pool, v_pool, table, lengths, q_positions,
@@ -326,6 +371,7 @@ def paged_besf_pallas(q, kq_pool, v_pool, table, lengths, q_positions,
     SH = Sq * Hq
     G = Hq // Hkv
     assert bits == cfg.bits and R == bs // 8 * C and C * X == D
+    assert bits <= 15, "the query's high half (q >> 6) must be exact in bf16"
 
     # Shared host-side prep with the oracle: (slot, query) rows flatten to
     # B*Sq independent Sq=1 decodes as far as quantization is concerned.
@@ -342,12 +388,19 @@ def paged_besf_pallas(q, kq_pool, v_pool, table, lengths, q_positions,
     def rows(x):                                  # [B*Sq, Hq, ..] -> [B, SH, n]
         return x.reshape(B, SH, -1)
 
+    # q = 64 * (q >> 6) + (q & 63): both halves are small integers (the
+    # query is INT12), exact in bf16, for the kernel's one-pass MXU dots.
+    q_split = jnp.stack([rows(q_bd >> 6), rows(q_bd & 63)],
+                        axis=1).astype(jnp.bfloat16)      # [B, 2, SH, C*L]
+
     def lut(m):                                   # [bits, B*Sq, Hq]
         return jnp.moveaxis(m, 0, -1).reshape(B, SH, bits)
 
     def per_row(x):                               # [B, Sq] -> [B, SH, 1]
         return jnp.repeat(x.astype(jnp.int32), Hq, axis=1)[..., None]
 
+    lo, hi = page_bounds(lengths.astype(jnp.int32),
+                         q_positions.astype(jnp.int32), bs, window)
     kernel = functools.partial(
         _paged_besf_kernel,
         bits=bits, page_size=bs, n_queries=Sq, chunks=C,
@@ -367,12 +420,13 @@ def paged_besf_pallas(q, kq_pool, v_pool, table, lengths, q_positions,
             pl.BlockSpec((1, 1, SH, bs), lambda b, j, *_: (b, j, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((B, MB, SH, bs), jnp.int8))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                    # tables (DMA addressing)
+        num_scalar_prefetch=3,            # tables, page range lo, hi
         grid=(B, MB),
         in_specs=[
             row_spec(1),                                         # q_pos
             row_spec(1),                                         # lengths
-            row_spec(C * L),                                     # q_bd
+            pl.BlockSpec((1, 2, SH, C * L),                      # q_bd
+                         lambda b, j, *_: (b, 0, 0, 0)),
             row_spec(bits),                                      # m_min
             row_spec(bits),                                      # m_max
             row_spec(1),                                         # scale_total
@@ -399,8 +453,9 @@ def paged_besf_pallas(q, kq_pool, v_pool, table, lengths, q_positions,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(table.astype(jnp.int32), per_row(q_positions), per_row(lengths),
-      rows(q_bd), lut(m_min), lut(m_max), rows(scale_total),
+    )(table.astype(jnp.int32), lo, hi, per_row(q_positions),
+      per_row(lengths),
+      q_split, lut(m_min), lut(m_max), rows(scale_total),
       rows(alpha_radius), jnp.repeat(v_scale, Dv)[None], kq_pool, v_pool)
     # Row (s, h) keeps its own KV head's lanes.
     out = res[0].reshape(B, Sq, Hq, Hkv, Dv)

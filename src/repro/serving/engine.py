@@ -47,6 +47,7 @@ import numpy as np
 # NOTE: repro.analysis.pool_sanitizer is imported lazily at pool
 # construction — it imports serving.kv_pool, and a module-level import
 # here would be circular through serving/__init__.
+from repro.kernels.paged_decode import page_bounds
 from repro.models import transformer as T
 from repro.models.attention import (POS_SENTINEL, PagedLayout,
                                     apply_inject_amax_rule,
@@ -974,6 +975,9 @@ class PagedEngine(_EngineCommon):
         self.counters = {"prefill_tokens": 0, "prefix_hit_tokens": 0,
                          "prefill_chunks": 0, "decode_tokens": 0,
                          "decode_steps": 0, "decode_kv_tokens": 0,
+                         # paged BESF kernel grid steps of one layer's
+                         # call, and those inside a slot's reach
+                         "decode_page_slots": 0, "decode_pages_reached": 0,
                          "requests_finished": 0,
                          "spec_ticks": 0, "spec_proposed": 0,
                          "spec_accepted": 0, "spec_bailouts": 0,
@@ -2343,6 +2347,7 @@ class PagedEngine(_EngineCommon):
         self.counters["decode_steps"] += 1
         self.counters["decode_kv_tokens"] += sum(
             int(self.lengths[i]) + 1 for i in active)
+        self._count_pages(self._decode_positions(active))
         with self.tracer.span("emit"):
             for i in active:
                 req = self.slots[i].req
@@ -2361,12 +2366,30 @@ class PagedEngine(_EngineCommon):
         if active is None:
             active = [i for i, st in enumerate(self.slots)
                       if st is not None and st.prefilled()]
-        positions = np.full((len(self.slots), 1), POS_SENTINEL, np.int32)
-        for i in active:
-            positions[i, 0] = self.lengths[i]
         tokens = jnp.asarray(self.last_token[:, None])
         caches = _attach_tables(self.caches, self.table, self.lengths)
-        return tokens, caches, jnp.asarray(positions)
+        return tokens, caches, jnp.asarray(self._decode_positions(active))
+
+    def _decode_positions(self, active: list[int]) -> np.ndarray:
+        """``[max_slots, 1]`` query positions of a plain decode tick over
+        ``active``; every other row at the pad sentinel."""
+        positions = np.full((len(self.slots), 1), POS_SENTINEL, np.int32)
+        positions[active, 0] = self.lengths[active]
+        return positions
+
+    def _count_pages(self, positions: np.ndarray) -> None:
+        """Add one decode step's paged BESF kernel grid, ``max_slots x
+        max_blocks_per_req`` steps a layer, and the steps inside each
+        slot's page range (``kernels/paged_decode.py:page_bounds``, a
+        layer without a window), from the step's query positions as the
+        attention layer turns them into fill levels: position + 1, or 0
+        for a padding query."""
+        real = positions != POS_SENTINEL
+        q_pos = np.where(real, positions, 0)
+        lo, hi = page_bounds(np.where(real, q_pos + 1, 0), q_pos,
+                             self._page, xp=np)
+        self.counters["decode_page_slots"] += self.table.size
+        self.counters["decode_pages_reached"] += int((hi - lo).sum())
 
     def programs(self) -> dict:
         """The jitted forwards this engine serves with, each with
@@ -2537,6 +2560,7 @@ class PagedEngine(_EngineCommon):
         self.counters["decode_steps"] += 1
         self.counters["decode_kv_tokens"] += sum(
             int(self.lengths[i]) + 1 + len(drafts[i]) for i in active)
+        self._count_pages(positions)
 
         for i in active:
             st = self.slots[i]

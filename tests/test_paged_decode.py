@@ -14,7 +14,8 @@ from repro.core.besf import (
     besf_attention_decode,
     besf_attention_decode_paged,
 )
-from repro.kernels.paged_decode import paged_bitstopper_decode
+from repro.kernels import paged_decode
+from repro.kernels.paged_decode import page_bounds, paged_bitstopper_decode
 from repro.models.attention import (
     POS_SENTINEL,
     AttnConfig,
@@ -116,6 +117,86 @@ def test_paged_kernel_matches_oracle(alpha, window, G):
     # pages past a row's fill level are never touched: zero planes fetched
     rounds = np.asarray(ora.rounds)
     assert rounds[1, 3] == 0 and (rounds[2, 2:] == 0).all()
+
+
+# (block tables, fill levels, window, the hand-counted page range [lo, hi)
+# of each slot): grid steps outside a slot's range are skipped whole.
+SKIP_CASES = {
+    "empty_slot": ([[1, 2, 3, 4], [5, 6, 0, 0], [0, 0, 0, 0]],
+                   [64, 20, 0], None, [(0, 4), (0, 2), (0, 0)]),
+    "first_page_of_wide_table": ([[1, 0, 0, 0, 0, 0, 0, 0],
+                                  [2, 3, 4, 5, 6, 7, 0, 0]],
+                                 [5, 90], None, [(0, 1), (0, 6)]),
+    # row 0 at position 79 sees tokens 60..79: pages 0-2 lie outside.
+    "windowed": ([[1, 2, 3, 4, 5], [6, 7, 0, 0, 0]],
+                 [80, 30], 20, [(3, 5), (0, 2)]),
+}
+
+
+def _walk_every_page(monkeypatch):
+    """Give every slot the page range [0, MB): the kernel then runs the
+    whole per-page body on every grid step, reached or not."""
+    def every_page(lengths, *_, **__):
+        B = lengths.shape[0]
+        return jnp.zeros((B,), jnp.int32), jnp.full((B,), 1 << 20, jnp.int32)
+
+    monkeypatch.setattr(paged_decode, "page_bounds", every_page)
+
+
+@pytest.mark.parametrize("case", sorted(SKIP_CASES))
+def test_paged_kernel_skips_unreachable_pages(case, monkeypatch):
+    """Grid steps that no query of their slot can reach run nothing, and
+    change nothing: an empty slot, a row on the first page of a wide
+    table, a window past a row's first pages.  Plane counts, survivors
+    and V fetches match the oracle bit for bit, every output matches the
+    kernel that walks every page bit for bit, and every skipped (slot,
+    page) reads zero planes and zero survivors."""
+    table, lengths, window, want = SKIP_CASES[case]
+    k_pool, v_pool, k_amax, v_amax = _pool_state(6)
+    bs = k_pool.shape[1]
+    Hq, D = 2 * k_pool.shape[2], k_pool.shape[3]
+    table = jnp.asarray(table, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    q_pos = jnp.maximum(lengths - 1, 0)
+    lo, hi = page_bounds(np.asarray(lengths)[:, None],
+                         np.asarray(q_pos)[:, None], bs, window, xp=np)
+    assert list(zip(lo.tolist(), hi.tolist())) == want
+
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.normal(size=(table.shape[0], Hq, D)) * 2,
+                    jnp.float32)
+    cfg = BitStopperConfig(alpha=0.6)
+    ora = besf_attention_decode_paged(q, k_pool, v_pool, table, lengths,
+                                      q_pos, k_amax, v_amax, cfg=cfg,
+                                      window=window)
+    kq_pool = _pack_pool(k_pool, k_amax)
+    ker = paged_bitstopper_decode(q, kq_pool, v_pool, table, lengths,
+                                  q_pos, k_amax, v_amax, cfg=cfg,
+                                  window=window, interpret=True)
+    for name in ("rounds", "survivors", "v_fetched"):
+        np.testing.assert_array_equal(np.asarray(getattr(ora, name)),
+                                      np.asarray(getattr(ker, name)))
+    np.testing.assert_allclose(np.asarray(ora.out), np.asarray(ker.out),
+                               atol=1e-6, rtol=1e-6)
+    _walk_every_page(monkeypatch)
+    out, rounds, surv = paged_decode.paged_besf_pallas(
+        q[:, None], kq_pool, v_pool, table, lengths[:, None],
+        q_pos[:, None], k_amax, v_amax, cfg=cfg, window=window,
+        interpret=True, stats=True)
+    np.testing.assert_array_equal(np.asarray(out[:, 0]), np.asarray(ker.out))
+    np.testing.assert_array_equal(np.asarray(rounds[:, 0]),
+                                  np.asarray(ker.rounds))
+    np.testing.assert_array_equal(
+        np.asarray(jnp.moveaxis(surv, 1, 2).reshape(ker.survivors.shape)),
+        np.asarray(ker.survivors))
+    rounds = np.asarray(ker.rounds)
+    surv = np.asarray(ker.survivors).reshape(table.shape[0], Hq, -1, bs)
+    for b, (lo_b, hi_b) in enumerate(want):
+        skipped = [j for j in range(table.shape[1]) if not lo_b <= j < hi_b]
+        assert (rounds[b, skipped] == 0).all()
+        assert not surv[b, :, skipped].any()
+        assert not np.asarray(ker.v_fetched)[b, skipped].any()
+        assert (rounds[b, lo_b:hi_b] > 0).all()
 
 
 def test_paged_oracle_matches_dense_gather_path():

@@ -222,6 +222,47 @@ def test_paged_bitstopper_decode_greedy_parity(model):
     assert req.generated == greedy
 
 
+def test_paged_decode_page_counters(model):
+    """``decode_page_slots`` / ``decode_pages_reached`` against a hand
+    count from the slots' fill levels: every decode step walks max_slots x
+    max_blocks_per_req grid steps, of which a decoding slot of fill level
+    n (its new token included) reaches ceil(n / page); an empty slot and
+    an idle engine reach nothing."""
+    cfg, params = model
+    eng = _paged(cfg, params, max_slots=3, page_size=8, max_len=64)
+    assert eng.table.shape == (3, 8)
+    idle = dict(eng.counters)
+    assert not eng.step()
+    assert eng.counters == idle
+    assert idle["decode_page_slots"] == idle["decode_pages_reached"] == 0
+
+    for r in _reqs(cfg, (6, 15), max_new=12):
+        eng.submit(r)
+    while not all(st is not None and st.prefilled()
+                  for st in eng.slots[:2]):
+        eng.step()
+    start = dict(eng.counters)
+    slots = reached = 0
+    for _ in range(6):
+        active = [i for i, st in enumerate(eng.slots) if st is not None]
+        assert active == [0, 1]
+        slots += 3 * 8
+        reached += sum(-(-(int(eng.lengths[i]) + 1) // 8) for i in active)
+        eng.step()
+    assert eng.counters["decode_steps"] - start["decode_steps"] == 6
+    assert (eng.counters["decode_page_slots"]
+            - start["decode_page_slots"]) == slots
+    assert (eng.counters["decode_pages_reached"]
+            - start["decode_pages_reached"]) == reached
+    assert reached < slots
+
+    while eng.step():
+        pass
+    done = dict(eng.counters)
+    assert not eng.step()
+    assert eng.counters == done
+
+
 def test_paged_chunked_prefill_invariance(model):
     """Chunk size must not change served tokens on the dense path, and a
     long prompt must actually take several prefill ticks."""

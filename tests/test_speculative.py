@@ -16,6 +16,7 @@ from repro.core.besf import (
     besf_attention_decode_paged,
     besf_attention_verify_paged,
 )
+from repro.kernels.paged_decode import page_bounds
 from repro.kernels.paged_verify import paged_bitstopper_verify
 from repro.models import transformer as T
 from repro.serving import (
@@ -148,6 +149,51 @@ def test_verify_kernel_matches_oracle(alpha, window, G):
     assert (rounds[2, :, 2:] == 0).all()      # row 2 ends mid page 2
     assert (rounds[2, 2] == 0).all()          # padding query: nothing
     assert (rounds[1, 0, 3] == 0)             # null table entry
+
+
+@pytest.mark.parametrize("window", [None, 16])
+def test_verify_kernel_skips_unreachable_pages(window):
+    """A Sq=4 draft block with one padding query over a wide table, beside
+    an all-padding slot: each slot's page range is the union of its real
+    queries' reach, and the kernel matches the oracle bit for bit on plane
+    counts, survivors and V fetches while every skipped (slot, page)
+    reads zero planes and zero survivors."""
+    k_pool, v_pool, k_amax, v_amax = _pool_state(8)
+    rng = np.random.default_rng(9)
+    bs, Hkv, D = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
+    kq_pool = qlib.pack_pool_planes(k_pool, k_amax, BITS)
+    table = jnp.asarray([[1, 2, 3, 4, 5, 6], [7, 0, 0, 0, 0, 0]], jnp.int32)
+    Sq = 4
+    q = jnp.asarray(rng.normal(size=(2, Sq, 2 * Hkv, D)) * 2, jnp.float32)
+    qpos = jnp.asarray([[46, 47, 48, 0], [0, 0, 0, 0]], jnp.int32)
+    lengths = jnp.asarray([[47, 48, 49, 0], [0, 0, 0, 0]], jnp.int32)
+    # Row 0 reaches tokens < 49 (pages 0-3); with the window its queries
+    # see from token 31 on, so page 0 lies outside.  Row 1 reaches none.
+    want = [(0 if window is None else 1, 4), (0, 0)]
+    lo, hi = page_bounds(np.asarray(lengths), np.asarray(qpos), bs, window,
+                         xp=np)
+    assert list(zip(lo.tolist(), hi.tolist())) == want
+    cfg = BitStopperConfig(alpha=0.6)
+    ora = besf_attention_verify_paged(q, k_pool, v_pool, table, lengths,
+                                      qpos, k_amax, v_amax, cfg=cfg,
+                                      window=window)
+    ker = paged_bitstopper_verify(q, kq_pool, v_pool, table, lengths, qpos,
+                                  k_amax, v_amax, cfg=cfg, window=window,
+                                  interpret=True)
+    for name in ("rounds", "survivors", "v_fetched"):
+        np.testing.assert_array_equal(np.asarray(getattr(ora, name)),
+                                      np.asarray(getattr(ker, name)))
+    np.testing.assert_allclose(np.asarray(ora.out), np.asarray(ker.out),
+                               atol=1e-6, rtol=1e-6)
+    rounds = np.asarray(ker.rounds)                       # [B, Sq, MB]
+    surv = np.asarray(ker.survivors).reshape(2, Sq, 2 * Hkv, -1, bs)
+    for b, (lo_b, hi_b) in enumerate(want):
+        skipped = [j for j in range(table.shape[1]) if not lo_b <= j < hi_b]
+        assert (rounds[b][:, skipped] == 0).all()
+        assert not surv[b][:, :, skipped].any()
+        assert not np.asarray(ker.v_fetched)[b][:, skipped].any()
+    assert (rounds[0].max(axis=0)[want[0][0]:4] > 0).all()
+    assert (rounds[0, 3] == 0).all()                      # padding query
 
 
 def test_verify_kernel_amortizes_plane_fetches():
