@@ -1,7 +1,8 @@
 """The served model as the benchmark builds it: the program's own
 ``ModelConfig`` for the configuration file's arch at the file's sizes,
-and random weights made on the device from the seed in the layout the
-program's transformer reads."""
+random weights made on the device from the seed in the layout the
+program's transformer reads, and the fused decode kernel's plane counts
+on the served caches."""
 
 from __future__ import annotations
 
@@ -9,13 +10,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.archs import jax_seed
+
 _DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
-
-
-def jax_seed(seed: int) -> int:
-    """A 31-bit key for JAX from a seed of any size (the driver's seeds
-    exceed 32 signed bits)."""
-    return int(np.random.default_rng(seed).integers(2 ** 31 - 1))
 
 
 def program_config(conf: dict):
@@ -106,3 +103,34 @@ def make_params(conf: dict, seed: int):
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
     return build(jax.random.PRNGKey(jax_seed(seed)))
+
+
+def plane_stats(engine, seed: int) -> dict | None:
+    """The fused kernel's own plane counts (``stats=True``) on every
+    layer's live cache, for random queries: planes fetched against
+    ``bits`` for every page the live rows reach.  None where no row is
+    live."""
+    from repro.kernels.paged_decode import paged_bitstopper_decode
+    active = [i for i, st in enumerate(engine.slots)
+              if st is not None and st.prefilled()]
+    if not active:
+        return None
+    cfg = engine.cfg
+    _, caches, _ = engine.decode_inputs(active)
+    c = caches["seg0"]["b0"]
+    rng = np.random.default_rng([seed, 11])
+    lengths = np.zeros(len(engine.slots), np.int32)
+    lengths[active] = engine.lengths[active]
+    q = jnp.asarray(rng.standard_normal(
+        (len(engine.slots), cfg.n_heads, cfg.head_dim)), cfg.activation_dtype)
+    ln = jnp.asarray(lengths)
+    fetched = dense = 0
+    for layer in range(cfg.n_layers):
+        out = paged_bitstopper_decode(
+            q, c["kq"][layer], c["v"][layer], c["table"][layer], ln,
+            jnp.maximum(ln - 1, 0), c["k_amax"][layer], c["v_amax"][layer],
+            cfg=cfg.bitstopper, stats=True)
+        rounds = np.asarray(out.rounds)
+        fetched += int(rounds.sum())
+        dense += int((rounds > 0).sum()) * cfg.bitstopper.bits
+    return {"fetched": fetched, "dense": dense}

@@ -32,15 +32,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.archs import round_fp8
+
 HI = jax.lax.Precision.HIGHEST
 NEG = -1e30
 QUERY_BLOCK = 256
-
-
-def _fp8(x, axis):
-    amax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
-    scale = jnp.maximum(amax, 1e-30) / 448.0
-    return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
 
 
 def _layer_norm(x, scale, bias, eps):
@@ -76,8 +72,8 @@ def _compiled(dims: tuple, t_pad: int, n_out: int, mode: str):
         the control)."""
         w = w.astype(jnp.float32)
         if fp8:
-            a = _fp8(a, -1)
-            w = _fp8(w, None)
+            a = round_fp8(a, -1)
+            w = round_fp8(w, None)
         return jnp.matmul(a, w, precision=HI)
 
     def attention(q, k, v, pos, t_real, n_prompt):

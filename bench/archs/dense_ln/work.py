@@ -1,8 +1,11 @@
 """Operations and bytes that the served model's calls need, from shapes.
 
 Everything here is computed from the configuration file's published sizes
-(``model`` section of ``bench/configs/<config>.json``) and from counts the
-run records; nothing is read from the program.  These are the numerators
+(``bench/configs/<config>.json``) and from the engine's counters over the
+traced slice; nothing is read from the program.  Every layer attends the
+same span, so two counters are enough: ``decode_tokens`` (tokens the
+decode steps emitted) and ``decode_kv_tokens`` (cached tokens they
+attended, summed over the rows of every step).  These are the numerators
 of the step's share of the chip's peak (``decode_step_mfu``) and of the
 fused decode kernel's roofline share (``besf_kernel_roofline``).
 """
@@ -30,29 +33,29 @@ def matmul_params_per_token(m: dict) -> int:
     return layers * (attn + ffn) + d * m["vocab_size"]
 
 
-def decode_step_flops(m: dict, tokens: int, kv_tokens: int) -> float:
-    """Model FLOPs of decode steps that emit ``tokens`` tokens while
-    attending ``kv_tokens`` cached tokens in all (summed over the rows of
-    every step): 2 per weight per token, and per layer 2 * Hq * Dh for the
-    scores and as much again for the weighted sum of V, per attended
-    token.  This is the dense model's count, the same whatever the
-    attention does with it."""
+def decode_step_flops(m: dict, counters: dict) -> float:
+    """Model FLOPs of decode steps that emit ``decode_tokens`` tokens while
+    attending ``decode_kv_tokens`` cached tokens in all: 2 per weight per
+    token, and per layer 2 * Hq * Dh for the scores and as much again for
+    the weighted sum of V, per attended token.  This is the dense model's
+    count, the same whatever the attention does with it."""
     layers, _, hq, _, dh = _dims(m)
-    return (2.0 * matmul_params_per_token(m) * tokens
-            + 4.0 * layers * hq * dh * kv_tokens)
+    return (2.0 * matmul_params_per_token(m) * counters["decode_tokens"]
+            + 4.0 * layers * hq * dh * counters["decode_kv_tokens"])
 
 
-def besf_kernel_work(m: dict, kv_tokens: int) -> tuple[float, float]:
+def besf_kernel_work(m: dict, counters: dict) -> tuple[float, float]:
     """``(flops, bytes)`` the fused paged BESF decode kernel needs over
-    ``kv_tokens`` attended (row, token) pairs, summed over all layers, when
-    no page terminates early: each attended token is scored against its
-    ``bits`` K bit planes (2 * Hq * Dh operations per plane) and weighted
-    into the output (2 * Hq * Dh), and its planes (``bits`` bits per K
-    element) and its V row (``v_bytes`` per element) are read once per KV
-    head.  Where LATS terminates pages, fewer bytes are read and this
-    count is then too high; see PERF.md."""
+    the ``decode_kv_tokens`` attended (row, token) pairs, summed over all
+    layers, when no page terminates early: each attended token is scored
+    against its ``bits`` K bit planes (2 * Hq * Dh operations per plane)
+    and weighted into the output (2 * Hq * Dh), and its planes (``bits``
+    bits per K element) and its V row (``kv_cache_bytes`` per element) are
+    read once per KV head.  Where LATS terminates pages, fewer bytes are
+    read and this count is then too high; see PERF.md."""
     layers, _, hq, hkv, dh = _dims(m)
     bits = m["bitstopper"]["bits"]
+    kv_tokens = counters["decode_kv_tokens"]
     flops = layers * kv_tokens * (bits * 2.0 * hq * dh + 2.0 * hq * dh)
     per_token = bits * hkv * dh / 8 + hkv * dh * m["kv_cache_bytes"]
     return flops, layers * kv_tokens * per_token
@@ -64,10 +67,3 @@ def kv_bytes_per_token(m: dict) -> int:
     layers, _, _, hkv, dh = _dims(m)
     bits = m["bitstopper"]["bits"]
     return layers * (2 * hkv * dh * m["kv_cache_bytes"] + bits * hkv * dh // 8)
-
-
-def least_time_s(flops: float, nbytes: float, peaks: dict) -> tuple[float, str]:
-    """The roofline's least time and which bound sets it."""
-    t_flops = flops / peaks["bf16_flops_per_s"]
-    t_bytes = nbytes / peaks["hbm_bytes_per_s"]
-    return (t_flops, "flops") if t_flops >= t_bytes else (t_bytes, "bytes")

@@ -1,10 +1,9 @@
 """Kernels: the fused paged BESF decode kernel's least time (the larger of
-its FLOPs at the bf16 peak and its bytes at HBM bandwidth, from
-``bench/work.py`` at the fill it ran) over its device time in the traced
-slice of the window (%)."""
+its FLOPs at the bf16 peak and its bytes at HBM bandwidth, from the arch
+family's ``work.besf_kernel_work`` at the fill it ran) over its device
+time in the traced slice of the window (%)."""
 
-from bench.metrics import traced
-from bench.work import besf_kernel_work, least_time_s
+from bench.archs import family, least_time_s
 
 # The kernel's device op is named after the jitted wrapper that calls the
 # Pallas kernel (``kernels/paged_decode.py``).
@@ -21,8 +20,12 @@ def kernel_s(rec):
 
 def read(rec):
     s = kernel_s(rec)
-    kv = s and traced(rec, "decode_kv_tokens")
-    if s is None or not kv or "peaks" not in rec:
+    if s is None or "peaks" not in rec:
         return None
-    t, _ = least_time_s(*besf_kernel_work(rec["conf"], kv), rec["peaks"])
+    conf = rec["conf"]
+    flops, nbytes = family(conf).work.besf_kernel_work(
+        conf, rec["trace"]["counters"])
+    if not nbytes:
+        return None
+    t, _ = least_time_s(flops, nbytes, rec["peaks"])
     return 100.0 * t / s

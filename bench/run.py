@@ -4,7 +4,9 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything a cell needs is data found by name: the configuration file
-(``bench/configs/<config>.json``), the traffic mix
+(``bench/configs/<config>.json``), whose ``arch_family`` names the
+package that builds its model, holds its plain reference and counts its
+work (``bench/archs/<arch_family>/``), the traffic mix
 (``bench/traffic/<traffic>.json``, whose ``loop`` names its generator),
 the cell's correctness limit (``bench/limits/<cell>.json``) and one reader
 per metric (``bench/metrics/<metric>.py``).
@@ -12,11 +14,11 @@ per metric (``bench/metrics/<metric>.py``).
 A run builds the model with random weights made on the device from the
 seed, warms up the prefill-chunk and decode shapes of the cell's traffic,
 drives the paged engine's ``submit``/``step`` with the traffic for
-``--seconds``, compares the served tokens with the plain reference
-(``bench/reference.py``), and prints one JSON line last on standard
-output.  ``--trace 0`` reports the cell's end-to-end metrics on the
-harness's clock; ``--trace 1`` traces the window with the JAX profiler and
-reports the per-layer metrics.  A run on anything but a TPU, or on fewer
+``--seconds``, compares the served tokens with the family's plain
+reference, and prints one JSON line last on standard output.
+``--trace 0`` reports the cell's end-to-end metrics on the harness's
+clock; ``--trace 1`` traces the window with the JAX profiler and reports
+the per-layer metrics.  A run on anything but a TPU, or on fewer
 chips than the cell asks for, exits non-zero and prints no result.
 """
 
@@ -43,6 +45,8 @@ for _p in (str(ROOT / "src"), str(ROOT)):
 
 import jax                      # noqa: E402
 import numpy as np              # noqa: E402
+
+from bench.archs import family, jax_seed  # noqa: E402
 
 BENCH = ROOT / "bench"
 TRACE_DIR = ROOT / ".bench_trace"
@@ -341,7 +345,7 @@ def served_gaps(cell: Cell, params, picked: list[Sent],
     served ones; with ``control`` (a reference mode, ``float8``) they are
     the tokens that the control puts first at the same positions of the
     same prompts and served tokens, in the served tokens' place."""
-    from bench.reference import logits_at
+    logits_at = family(cell.conf).reference.logits_at
     t_pad, n_out = reference_shape(cell)
     widest = 0.0
     tokens = 0
@@ -390,51 +394,20 @@ def window_record(sent, unsent, seconds, counters, engine, setup_s) -> dict:
     }
 
 
-def plane_stats(engine, seed: int) -> dict | None:
-    """The fused kernel's own plane counts (``stats=True``) on every
-    layer's live cache at the window's close, for random queries: planes
-    fetched against 12 for every page the live rows reach."""
-    import jax.numpy as jnp
-    from repro.kernels.paged_decode import paged_bitstopper_decode
-    active = [i for i, st in enumerate(engine.slots)
-              if st is not None and st.prefilled()]
-    if not active:
-        return None
-    cfg = engine.cfg
-    _, caches, _ = engine.decode_inputs(active)
-    c = caches["seg0"]["b0"]
-    rng = np.random.default_rng([seed, 11])
-    lengths = np.zeros(len(engine.slots), np.int32)
-    lengths[active] = engine.lengths[active]
-    q = jnp.asarray(rng.standard_normal(
-        (len(engine.slots), cfg.n_heads, cfg.head_dim)), cfg.activation_dtype)
-    ln = jnp.asarray(lengths)
-    fetched = dense = 0
-    for layer in range(cfg.n_layers):
-        out = paged_bitstopper_decode(
-            q, c["kq"][layer], c["v"][layer], c["table"][layer], ln,
-            jnp.maximum(ln - 1, 0), c["k_amax"][layer], c["v_amax"][layer],
-            cfg=cfg.bitstopper, stats=True)
-        rounds = np.asarray(out.rounds)
-        fetched += int(rounds.sum())
-        dense += int((rounds > 0).sum()) * cfg.bitstopper.bits
-    return {"fetched": fetched, "dense": dense}
-
-
 def run(cell: Cell, seed: int, seconds: float, trace: bool,
         require_tpu: bool = True, control: str | None = None) -> dict:
     """One run of ``cell``; returns the result line as a dict.  With
     ``control`` (``calibrate.py --control``) the control's tokens are
     compared in the served tokens' place, and the run has to come out not
     correct."""
-    from bench.model import jax_seed, make_params, program_config
     from bench.traffic import make_schedule
     from repro.serving import PagedEngine
 
     device = device_info(cell.chips, require_tpu)
     enable_cache()
-    cfg = program_config(cell.conf)
-    params = make_params(cell.conf, seed)
+    model = family(cell.conf).model
+    cfg = model.program_config(cell.conf)
+    params = model.make_params(cell.conf, seed)
     engine = PagedEngine(cfg, params, serve_config(cell))
     engine.begin(jax_seed(seed))
     schedule = make_schedule(cell.mix, seed, cfg.vocab)
@@ -467,7 +440,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         if rec["trace"] is not None:
             a, b = d["traced"]["after"], d["traced"]["before"]
             rec["trace"]["counters"] = {k: a[k] - b.get(k, 0) for k in a}
-        rec["planes"] = plane_stats(engine, seed)
+        rec["planes"] = model.plane_stats(engine, seed)
         if rec["trace"] is not None:
             device["busy_s"] = rec["trace"]["busy_s"]
             device["window_s"] = rec["trace"]["window_s"]

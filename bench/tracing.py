@@ -399,15 +399,16 @@ def run(cell, seed: int, seconds: float, trace: bool, clock: bool = True,
     slowest steps."""
     import jax
     from bench import run as R
-    from bench.model import jax_seed, make_params, program_config
+    from bench.archs import family, jax_seed
     from bench.traffic import make_schedule
     from repro.runtime import SystemClock, Tracer
     from repro.serving import PagedEngine
 
     device = R.device_info(cell.chips, require_tpu)
     R.enable_cache()
-    cfg = program_config(cell.conf)
-    params = make_params(cell.conf, seed)
+    model = family(cell.conf).model
+    cfg = model.program_config(cell.conf)
+    params = model.make_params(cell.conf, seed)
     tracer = Tracer(SystemClock()) if clock else None
     engine = PagedEngine(cfg, params, R.serve_config(cell), tracer=tracer)
     engine.begin(jax_seed(seed))

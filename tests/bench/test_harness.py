@@ -1,12 +1,16 @@
 """The harness end to end on the CPU, at the tiny cell's size, with the
 look for a chip skipped: a sound run is correct, a run whose served
 tokens are altered where they are produced is not, the float8 control is
-not, a cell, mix and metric added as files alone run, and without a chip
-(or without the program) no result is printed."""
+not, a cell, mix and metric added as files alone run, as does a new arch
+family, and without a chip (or without the program) no result is
+printed."""
 
+import copy
+import gzip
 import json
 import os
 import pathlib
+import shutil
 import subprocess
 import sys
 
@@ -15,6 +19,7 @@ import numpy as np
 import tiny
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
+DATA = ROOT / "bench" / "testdata"
 # The tiny cell's limit, set as a chip cell's is: the program read at
 # most 0.0328 over ten seeds (CPU, bf16 program against the float32
 # reference); the float8 control read 0.287 to 0.435 on the same seeds.
@@ -101,6 +106,86 @@ def test_a_cell_mix_and_metric_are_added_as_files_alone(no_cache, tmp_path):
             path.unlink(missing_ok=True)
     assert out["correct"] is True
     assert out["metrics"][f"{tag}_tokens"]["value"] > 0
+
+
+# A family package that serves as dense_ln does, reached through the
+# lookup, and records each call.
+DELEGATING = '''from bench.archs import family
+
+_BASE = family({{"arch_family": "dense_ln"}}).{module}
+CALLS = []
+
+
+def _recorded(name):
+    def call(*args, **kwargs):
+        CALLS.append(name)
+        return getattr(_BASE, name)(*args, **kwargs)
+    return call
+
+
+{defs}
+'''
+
+
+def test_a_new_arch_family_is_added_as_files_alone(no_cache, tmp_path,
+                                                   monkeypatch):
+    """A configuration naming a new arch family, the family's package under
+    ``bench/archs/``, a mix, a limit and entries in a copy of
+    BENCHMARK.json run with no change to the harness: the run is correct,
+    and the new family built the weights, gave the reference logits and
+    counted the kernel's work.  The CPU has no device plane to trace, so a
+    slice recorded on the chip stands in for the trace and the chip's
+    peaks are given, so that the work readers run."""
+    import bench.trace
+    from bench.archs import FUNCTIONS, family
+    tag = f"a{os.getpid()}"
+    pkg = ROOT / "bench" / "archs" / tag
+    files = {pkg / "__init__.py": ""}
+    for module, functions in FUNCTIONS.items():
+        files[pkg / f"{module}.py"] = DELEGATING.format(
+            module=module, defs="\n".join(f"{f} = _recorded({f!r})"
+                                          for f in functions))
+    conf = dict(tiny.CONF, arch_family=tag)
+    files.update({
+        ROOT / f"bench/configs/{tag}.json": json.dumps(conf),
+        ROOT / f"bench/traffic/{tag}.json": json.dumps(tiny.MIX),
+        ROOT / f"bench/limits/{tag}.cell.json":
+            json.dumps({"served_logit_gap": TINY_LIMIT}),
+    })
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": tag, "file": f"bench/configs/{tag}.json"})
+    spec["workloads"].append({"name": f"{tag}.cell", "config": tag,
+                               "traffic": tag, "chips": 1})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        m.get("workloads", []).append(f"{tag}.cell")
+    bench_file = tmp_path / "BENCHMARK.json"
+    bench_file.write_text(json.dumps(spec))
+    with gzip.open(DATA / "long_decode_window.events.json.gz", "rt") as f:
+        recorded = bench.trace.reduce_events(json.load(f))
+    monkeypatch.setattr(bench.trace, "reduce_dir",
+                        lambda *a, **k: copy.deepcopy(recorded))
+    monkeypatch.setattr(no_cache, "device_info", lambda chips, req: {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    try:
+        pkg.mkdir()
+        for path, text in files.items():
+            path.write_text(text)
+        cell = no_cache.load_cell(f"{tag}.cell", bench_file)
+        fam = family(cell.conf)
+        out = no_cache.run(cell, seed=SEED, seconds=2.0, trace=True,
+                           require_tpu=False)
+        calls = {m: list(getattr(fam, m).CALLS) for m in FUNCTIONS}
+    finally:
+        for path in files:
+            path.unlink(missing_ok=True)
+        shutil.rmtree(pkg, ignore_errors=True)
+    assert fam.name == tag
+    assert out["correct"] is True
+    assert {"program_config", "make_params", "plane_stats"} <= \
+        set(calls["model"])
+    assert "logits_at" in calls["reference"]
+    assert {"besf_kernel_work", "decode_step_flops"} <= set(calls["work"])
+    assert {"besf_kernel_roofline", "decode_step_mfu"} <= set(out["metrics"])
 
 
 def test_no_chip_no_result(capsys):

@@ -23,12 +23,14 @@ SEED = 2 ** 31 + 4242
 
 @pytest.fixture(scope="module")
 def engine():
-    from bench.model import make_params, program_config
+    from bench.archs import family
     from bench.run import serve_config
     from repro.serving import PagedEngine
     cell = tiny.cell()
-    cfg = program_config(cell.conf)
-    return PagedEngine(cfg, make_params(cell.conf, SEED), serve_config(cell))
+    model = family(cell.conf).model
+    cfg = model.program_config(cell.conf)
+    return PagedEngine(cfg, model.make_params(cell.conf, SEED),
+                       serve_config(cell))
 
 
 def _op_names(text):

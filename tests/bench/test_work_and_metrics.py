@@ -1,6 +1,6 @@
 """The benchmark's arithmetic on the CPU: operations and bytes from
-shapes, every metric reader on a synthetic run record, and the traffic
-generators."""
+shapes (the ``dense_ln`` family's, by hand), every metric reader on a
+synthetic run record, and the traffic generators."""
 
 import importlib
 import json
@@ -10,17 +10,18 @@ import pathlib
 import numpy as np
 import pytest
 
-from bench import work
+from bench.archs import family, least_time_s
 from bench.peaks import peaks_for
 from bench.traffic import load_mix, make_schedule
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 
-SMALL = {"num_hidden_layers": 2, "hidden_size": 8, "num_attention_heads": 2,
-         "num_key_value_heads": 1, "head_dim": 4, "intermediate_size": 16,
-         "vocab_size": 10, "ffn_gated": True, "kv_cache_bytes": 4,
-         "bitstopper": {"bits": 12}}
+SMALL = {"arch_family": "dense_ln", "num_hidden_layers": 2,
+         "hidden_size": 8, "num_attention_heads": 2, "num_key_value_heads": 1,
+         "head_dim": 4, "intermediate_size": 16, "vocab_size": 10,
+         "ffn_gated": True, "kv_cache_bytes": 4, "bitstopper": {"bits": 12}}
+work = family(SMALL).work
 
 
 def test_matmul_params_by_hand():
@@ -35,16 +36,17 @@ def test_matmul_params_by_hand():
 def test_decode_flops_by_hand():
     # 3 tokens through 1232 weights, 50 attended tokens x 2 layers x
     # (2 heads x 4 dims) x 4
-    assert work.decode_step_flops(SMALL, 3, 50) == 2 * 1232 * 3 + 3200
+    counters = {"decode_tokens": 3, "decode_kv_tokens": 50}
+    assert work.decode_step_flops(SMALL, counters) == 2 * 1232 * 3 + 3200
 
 
 def test_besf_kernel_work_by_hand():
-    flops, nbytes = work.besf_kernel_work(SMALL, 50)
+    flops, nbytes = work.besf_kernel_work(SMALL, {"decode_kv_tokens": 50})
     # per layer and token: 12 planes x 2 x 2 heads x 4 dims + 2 x 8
     assert flops == 2 * 50 * (12 * 16 + 16)
     # per layer and token: 12 bits x 1 KV head x 4 dims / 8 + 4 x 4 bytes
     assert nbytes == 2 * 50 * (6 + 16)
-    t, bound = work.least_time_s(flops, nbytes, peaks_for("TPU v5 lite"))
+    t, bound = least_time_s(flops, nbytes, peaks_for("TPU v5 lite"))
     assert bound == "bytes" and t == pytest.approx(2200 / 819e9)
 
 
@@ -122,12 +124,15 @@ def test_itl_is_the_tail_of_every_gap():
 def test_shares_of_a_peak_follow_from_work():
     rec = synthetic_record()
     mfu = importlib.import_module("bench.metrics.decode_step_mfu").read(rec)
-    flops = work.decode_step_flops(rec["conf"], 6, 15000)
+    counters = {"decode_tokens": 6, "decode_kv_tokens": 15000}
+    assert counters.items() <= rec["trace"]["counters"].items()
+    own = family(rec["conf"]).work
+    flops = own.decode_step_flops(rec["conf"], counters)
     assert mfu == pytest.approx(100 * flops / (0.8 * 197e12))
     roof = importlib.import_module(
         "bench.metrics.besf_kernel_roofline").read(rec)
-    t, bound = work.least_time_s(*work.besf_kernel_work(rec["conf"], 15000),
-                                 rec["peaks"])
+    t, bound = least_time_s(*own.besf_kernel_work(rec["conf"], counters),
+                            rec["peaks"])
     assert bound == "bytes"
     assert roof == pytest.approx(100 * t / 0.5)
 

@@ -10,7 +10,7 @@ import pathlib
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 
 CONF = {
-    "arch": "stablelm-1.6b",
+    "arch": "stablelm-1.6b", "arch_family": "dense_ln",
     "num_hidden_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
     "num_key_value_heads": 4, "head_dim": 16, "intermediate_size": 128,
     "vocab_size": 256, "rope_theta": 10000, "use_qkv_bias": True,
