@@ -10,6 +10,7 @@ tests; the full configs are exercised only through the dry-run.
 from __future__ import annotations
 
 from repro.models.config import BlockSpec, ModelConfig, uniform_segments
+from repro.models.layers import Yarn
 
 
 def _dense(name, n_layers, d_model, heads, kv, d_ff, vocab, head_dim=None,
@@ -107,6 +108,26 @@ def deepseek_v3_671b():
     )
 
 
+def mellum2_12b_a2_5b():
+    """Mellum2-12B-A2.5B [hf:JetBrains/Mellum2-12B-A2.5B-Instruct], written
+    as Qwen3-MoE's layer (its config's keys): GQA 32/4 at head 128 with
+    per-head q/k RMSNorm; three 1024-token window layers on plain RoPE,
+    then one full layer on YaRN (x16 over 8192), seven times; every FFN
+    64 experts of 896, top-8 renormalized, no shared expert."""
+    local = BlockSpec("local_attn", "moe")
+    unit = (local, local, local, BlockSpec("attn", "moe"))
+    return ModelConfig(
+        name="mellum2-12b-a2.5b", family="moe", d_model=2304, vocab=98304,
+        segments=((unit, 7),),                     # 28 layers
+        n_heads=32, n_kv_heads=4, head_dim=128, rope_theta=500000.0,
+        rope_yarn=Yarn(factor=16.0, original_max_position=8192,
+                       beta_fast=32.0, beta_slow=1.0,
+                       attention_factor=1.2772588722239782),
+        qk_norm=True, window=1024,
+        n_routed=64, top_k=8, d_expert=896, tie_embeddings=False,
+    )
+
+
 def llava_next_34b():
     """[vlm] backbone only; anyres patch embeddings stubbed."""
     return _dense("llava-next-34b", 60, 7168, 56, 8, 20480, 64000,
@@ -131,6 +152,7 @@ ARCHS = {
     "qwen2-moe-a2.7b": qwen2_moe_a2_7b,
     "deepseek-v3-671b": deepseek_v3_671b,
     "llava-next-34b": llava_next_34b,
+    "mellum2-12b-a2.5b": mellum2_12b_a2_5b,
     "paper-opt1.3b": paper_opt1_3b,
 }
 

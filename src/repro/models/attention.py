@@ -58,6 +58,9 @@ class AttnConfig:
     head_dim: int
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    yarn: L.Yarn | None = None         # YaRN-scaled RoPE (None = plain)
+    qk_norm: bool = False              # RMSNorm over head_dim on q and k,
+                                       # before RoPE
     window: int | None = None          # sliding-window size (None = global)
     causal: bool = True
     impl: str = "xla"
@@ -77,7 +80,7 @@ class AttnConfig:
 
 def init_attention(key, cfg: AttnConfig, dtype=jnp.float32):
     kq, kk, kv, ko = jax.random.split(key, 4)
-    return {
+    p = {
         "wq": L.init_linear(kq, cfg.d_model, (cfg.n_heads, cfg.head_dim),
                             cfg.qkv_bias, dtype),
         "wk": L.init_linear(kk, cfg.d_model, (cfg.n_kv_heads, cfg.head_dim),
@@ -87,6 +90,10 @@ def init_attention(key, cfg: AttnConfig, dtype=jnp.float32):
         "wo": L.init_linear(ko, cfg.n_heads * cfg.head_dim, cfg.d_model,
                             False, dtype),
     }
+    if cfg.qk_norm:
+        p["q_norm"] = L.init_rmsnorm(cfg.head_dim, dtype)
+        p["k_norm"] = L.init_rmsnorm(cfg.head_dim, dtype)
+    return p
 
 
 # ---------------------------------------------------------------------------
@@ -907,9 +914,12 @@ def attention(
     q = L.linear(p["wq"], x)                         # [B, S, Hq, D]
     k = L.linear(p["wk"], x)                         # [B, S, Hkv, D]
     v = L.linear(p["wv"], x)
+    if cfg.qk_norm:
+        q = L.rms_norm(p["q_norm"], q)
+        k = L.rms_norm(p["k_norm"], k)
     rope_pos = positions if positions.ndim == 2 else positions[None, :]
-    q = L.rope(q, rope_pos, cfg.rope_theta)
-    k = L.rope(k, rope_pos, cfg.rope_theta)
+    q = L.rope(q, rope_pos, cfg.rope_theta, cfg.yarn)
+    k = L.rope(k, rope_pos, cfg.rope_theta, cfg.yarn)
     if positions.ndim == 2:
         # Zero pad rows (bucketed-prefill sentinel positions): their k/v are
         # dropped by the cache scatter, and zero q rows keep the BitStopper

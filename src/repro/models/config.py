@@ -21,6 +21,7 @@ import dataclasses
 import jax.numpy as jnp
 
 from repro.core.besf import BitStopperConfig
+from repro.models.layers import Yarn
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,6 +44,9 @@ class ModelConfig:
     d_ff: int = 0
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    rope_yarn: Yarn | None = None  # YaRN on full-attention layers
+                                   # (window layers keep plain RoPE)
+    qk_norm: bool = False         # RMSNorm over head_dim on q and k
     window: int | None = None
     act: str = "swiglu"
     norm: str = "rms"             # rms | ln
@@ -61,6 +65,10 @@ class ModelConfig:
     d_shared: int = 0
     moe_capacity_factor: float = 1.25
     moe_resident: bool = False     # decode: fully-sharded resident experts
+    moe_dropless: bool = False     # every token reaches every expert it
+                                   # picked (serving; capacity = tokens)
+    n_experts_held: int = 0        # experts this device holds, the range
+    expert_offset: int = 0         # [offset, offset + held); 0 = all
     attn_chunk: int = 512          # chunked-attention tile size (xla path)
     # SSM
     ssm_state: int = 0
@@ -108,6 +116,7 @@ class ModelConfig:
             d_model=self.d_model, n_heads=self.n_heads,
             n_kv_heads=self.n_kv_heads, head_dim=self.head_dim,
             qkv_bias=self.qkv_bias, rope_theta=self.rope_theta,
+            yarn=None if local else self.rope_yarn, qk_norm=self.qk_norm,
             window=self.window if local else None,
             impl=self.attn_impl, bitstopper=self.bitstopper,
             chunk_q=self.attn_chunk, chunk_k=self.attn_chunk,
@@ -131,7 +140,9 @@ class ModelConfig:
             d_expert=self.d_expert, n_shared=self.n_shared,
             d_shared=self.d_shared, act=self.act,
             capacity_factor=self.moe_capacity_factor,
-            resident=self.moe_resident,
+            resident=self.moe_resident, dropless=self.moe_dropless,
+            n_held=self.n_experts_held,
+            offset=self.expert_offset,
         )
 
     def ssm_config(self):

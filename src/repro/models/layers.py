@@ -8,10 +8,12 @@ All matmuls run in the array dtype with f32 accumulation via
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def truncated_normal_init(key, shape, scale, dtype=jnp.float32):
@@ -103,14 +105,53 @@ def init_norm(d: int, kind: str = "rms", dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 
-def rope(x, positions, theta: float = 10000.0):
-    """Rotary embedding.  x [..., S, H, Dh] (Dh even), positions [..., S]."""
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN context extension of RoPE (arXiv:2309.00071): frequencies
+    between the two correction dims ramp from the original (fast dims) to
+    the original over ``factor`` (slow dims), and cos/sin are scaled by
+    ``attention_factor``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+def yarn_inv_freq(dh: int, theta: float, yarn: Yarn):
+    """YaRN's ``[Dh/2]`` inverse frequencies (float32): RoPE's, blended
+    with RoPE's over ``factor`` between the truncated correction dims."""
+    base = theta ** (np.arange(0, dh, 2, dtype=np.float32) / dh)
+
+    def corr_dim(rotations):
+        return (dh * math.log(yarn.original_max_position
+                              / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dh - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dh // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extrap = 1 - ramp
+    inv = (1.0 / (yarn.factor * base)) * ramp + (1.0 / base) * extrap
+    return jnp.asarray(inv, jnp.float32)
+
+
+def rope(x, positions, theta: float = 10000.0, yarn: Yarn | None = None):
+    """Rotary embedding.  x [..., S, H, Dh] (Dh even), positions [..., S].
+    With ``yarn``, YaRN's frequencies, and cos/sin times its
+    ``attention_factor``."""
     dh = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)   # [Dh/2]
+    freqs = (theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+             if yarn is None else yarn_inv_freq(dh, theta, yarn))       # [Dh/2]
     angles = positions[..., None].astype(jnp.float32) * freqs          # [..., S, Dh/2]
     # broadcast over the heads axis
     angles = angles[..., None, :]                                      # [..., S, 1, Dh/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -27,8 +27,17 @@ already arrive):
 
 Tokens beyond an expert's capacity are dropped (standard; the router aux
 loss keeps loads balanced, and capacity_factor=1.25 makes drops rare).
-The router computes in f32, softmax-after-top-k renormalization behind a
-flag, Switch-style load-balance aux loss.
+Serving sets ``dropless``: capacity is the token count, so a token's
+output never depends on how its batch or prefill chunk was routed.
+
+A layer may hold only a range of the experts, ``[offset, offset +
+n_held)`` of the ``n_routed`` its router scores (a chip's share under
+expert parallelism): it computes only those experts' part of each
+token's output, and rows routed elsewhere contribute nothing.
+
+The router computes in f32 at full precision, softmax over all experts
+then top-k with the kept probabilities renormalized behind a flag,
+Switch-style load-balance aux loss.
 """
 
 from __future__ import annotations
@@ -57,13 +66,23 @@ class MoEConfig:
     resident: bool = False         # decode: experts sharded over the FULL
                                    # mesh (1/dev), tokens gathered — no
                                    # per-layer weight gathers
+    dropless: bool = False         # capacity = the tokens routed (no row
+                                   # is ever dropped: a token picks an
+                                   # expert at most once)
+    n_held: int = 0                # experts held here: [offset,
+    offset: int = 0                # offset + n_held) of the n_routed the
+                                   # router scores (0 = all of them)
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_routed
 
 
 def init_moe(key, cfg: MoEConfig, dtype=jnp.float32):
     ks = jax.random.split(key, 5)
-    E, D, H = cfg.n_routed, cfg.d_model, cfg.d_expert
+    E, D, H = cfg.held, cfg.d_model, cfg.d_expert
     p = {
-        "router": L.init_linear(ks[0], D, E, False, jnp.float32),
+        "router": L.init_linear(ks[0], D, cfg.n_routed, False, dtype),
         "wi_gate": L.truncated_normal_init(ks[1], (E, D, H), 1.0, dtype),
         "wi_up": L.truncated_normal_init(ks[2], (E, D, H), 1.0, dtype),
         "wo": L.truncated_normal_init(ks[3], (E, H, D), 1.0, dtype),
@@ -75,7 +94,9 @@ def init_moe(key, cfg: MoEConfig, dtype=jnp.float32):
 
 def _route(p, x2d, cfg: MoEConfig):
     """x2d [T, D] → (weights [T,k], expert_ids [T,k], aux_loss)."""
-    logits = L.linear(p["router"], x2d.astype(jnp.float32)) * cfg.router_scale
+    logits = jnp.dot(x2d.astype(jnp.float32),
+                     p["router"]["w"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST) * cfg.router_scale
     probs = jax.nn.softmax(logits, axis=-1)                     # [T, E]
     top_p, top_i = jax.lax.top_k(probs, cfg.top_k)              # [T, k]
     if cfg.norm_topk:
@@ -89,8 +110,11 @@ def _route(p, x2d, cfg: MoEConfig):
     return top_p, top_i, aux
 
 
-def _capacity(T: int, top_k: int, n_experts: int, factor: float) -> int:
-    c = int(factor * T * top_k / max(n_experts, 1))
+def _capacity(T: int, cfg: MoEConfig) -> int:
+    """Rows per expert buffer for ``T`` tokens: every token where
+    ``cfg.dropless``, else ``capacity_factor`` times the mean load."""
+    c = T if cfg.dropless else int(
+        cfg.capacity_factor * T * cfg.top_k / max(cfg.n_routed, 1))
     return max(8, -(-c // 8) * 8)          # round up to a multiple of 8
 
 
@@ -146,6 +170,11 @@ def _local_moe(p_w, x2d, top_p, top_i, cfg: MoEConfig,
 
 def moe_ffn(p, x, cfg: MoEConfig, mesh=None, ep_axis: str = "model"):
     """Full MoE block.  x [B, S, D] → (out, aux_loss)."""
+    with jax.named_scope("moe"):
+        return _moe_ffn(p, x, cfg, mesh, ep_axis)
+
+
+def _moe_ffn(p, x, cfg: MoEConfig, mesh, ep_axis: str):
     B, S, D = x.shape
     x2d = x.reshape(B * S, D)
     top_p, top_i, aux = _route(p, x2d, cfg)
@@ -153,20 +182,22 @@ def moe_ffn(p, x, cfg: MoEConfig, mesh=None, ep_axis: str = "model"):
     tp = mesh.shape[ep_axis] if (mesh is not None and ep_axis in mesh.shape) else 1
 
     if tp == 1:
-        cap = _capacity(B * S, cfg.top_k, cfg.n_routed, cfg.capacity_factor)
-        out2d = _local_moe(p, x2d, top_p, top_i, cfg, 0, cfg.n_routed, cap)
+        # This device's experts, [offset, offset + held): rows routed
+        # elsewhere contribute nothing here.
+        out2d = _local_moe(p, x2d, top_p, top_i, cfg, cfg.offset, cfg.held,
+                           _capacity(B * S, cfg))
     else:
         from jax.sharding import PartitionSpec as P
         dp = tuple(a for a in mesh.axis_names if a != ep_axis)
 
         ep_pair = ("data", ep_axis)
         resident_ok = (cfg.resident and "data" in mesh.shape
-                       and cfg.n_routed % (mesh.shape["data"] * tp) == 0)
+                       and cfg.held % (mesh.shape["data"] * tp) == 0)
         if resident_ok:
             # ---- resident EP (decode): experts sharded over data×model —
             # weights stay put; the (tiny) decode token batch is gathered.
             n_grp = mesh.shape["data"] * tp
-            n_local = cfg.n_routed // n_grp
+            n_local = cfg.held // n_grp
             tok_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
 
             def res_body(wig, wiu, wog, xs, tpp, tii):
@@ -175,10 +206,10 @@ def moe_ffn(p, x, cfg: MoEConfig, mesh=None, ep_axis: str = "model"):
                 tig = jax.lax.all_gather(tii, tok_axes, axis=0, tiled=True)
                 gidx = (jax.lax.axis_index("data") * tp
                         + jax.lax.axis_index(ep_axis))
-                cap = _capacity(xg.shape[0], cfg.top_k, cfg.n_routed,
-                                cfg.capacity_factor)
+                cap = _capacity(xg.shape[0], cfg)
                 y = _local_moe({"wi_gate": wig, "wi_up": wiu, "wo": wog},
-                               xg, tpg, tig, cfg, gidx * n_local, n_local,
+                               xg, tpg, tig, cfg,
+                               cfg.offset + gidx * n_local, n_local,
                                cap)
                 y = jax.lax.psum(y, ("data", ep_axis))
                 # take back this shard's token slice
@@ -196,16 +227,16 @@ def moe_ffn(p, x, cfg: MoEConfig, mesh=None, ep_axis: str = "model"):
                 out_specs=P(dp),
                 check_vma=False,
             )(p["wi_gate"], p["wi_up"], p["wo"], x2d, top_p, top_i)
-        elif cfg.n_routed % tp == 0:
+        elif cfg.held % tp == 0:
             # ---- EP: experts sharded over "model".
-            n_local = cfg.n_routed // tp
+            n_local = cfg.held // tp
 
             def ep_body(wig, wiu, wog, xs, tpp, tii):
                 idx = jax.lax.axis_index(ep_axis)
-                cap = _capacity(xs.shape[0], cfg.top_k, cfg.n_routed,
-                                cfg.capacity_factor)
+                cap = _capacity(xs.shape[0], cfg)
                 y = _local_moe({"wi_gate": wig, "wi_up": wiu, "wo": wog},
-                               xs, tpp, tii, cfg, idx * n_local, n_local, cap)
+                               xs, tpp, tii, cfg, cfg.offset + idx * n_local,
+                               n_local, cap)
                 return jax.lax.psum(y, ep_axis)
 
             out2d = jax.shard_map(
@@ -218,10 +249,9 @@ def moe_ffn(p, x, cfg: MoEConfig, mesh=None, ep_axis: str = "model"):
         else:
             # ---- expert-TP: every shard, all experts, 1/tp of hidden dim.
             def tpx_body(wig, wiu, wog, xs, tpp, tii):
-                cap = _capacity(xs.shape[0], cfg.top_k, cfg.n_routed,
-                                cfg.capacity_factor)
+                cap = _capacity(xs.shape[0], cfg)
                 y = _local_moe({"wi_gate": wig, "wi_up": wiu, "wo": wog},
-                               xs, tpp, tii, cfg, 0, cfg.n_routed, cap)
+                               xs, tpp, tii, cfg, cfg.offset, cfg.held, cap)
                 return jax.lax.psum(y, ep_axis)
 
             out2d = jax.shard_map(

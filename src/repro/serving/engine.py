@@ -862,7 +862,10 @@ class PagedEngine(_EngineCommon):
                     f"pack 8 tokens/byte), got page_size={scfg.page_size}; "
                     f"pass fused_decode=False to serve through the gather "
                     f"oracle instead")
-        cfg = self.cfg = cfg.replace(fused_decode=bool(fused))
+        # Serving routes dropless: a token's expert outputs never depend on
+        # how its chunk or decode batch was routed (models/moe.py).
+        cfg = self.cfg = cfg.replace(fused_decode=bool(fused),
+                                     moe_dropless=True)
         self.params = params
         self.scfg = scfg
         # Mesh-sharded serving: slots over "data", KV heads (paged pools +
@@ -885,6 +888,10 @@ class PagedEngine(_EngineCommon):
         self._dtype = (jnp.bfloat16 if scfg.cache_dtype == "bfloat16"
                        else jnp.float32)
         self._page = scfg.page_size
+        # The span window layers attend (None: the model has none).
+        self._window = (cfg.window if any(
+            spec.mixer == "local_attn" for unit, _ in cfg.segments
+            for spec in unit) else None)
         self._mb = scfg.resolved_max_blocks()
         self._chunk = scfg.resolved_chunk()
         self.layout = PagedLayout(scfg.resolved_pool_blocks(), self._page,
@@ -978,6 +985,10 @@ class PagedEngine(_EngineCommon):
                          # paged BESF kernel grid steps of one layer's
                          # call, and those inside a slot's reach
                          "decode_page_slots": 0, "decode_pages_reached": 0,
+                         # one window layer's attended tokens and grid
+                         # steps reached (0 without window layers)
+                         "decode_window_kv_tokens": 0,
+                         "decode_window_pages_reached": 0,
                          "requests_finished": 0,
                          "spec_ticks": 0, "spec_proposed": 0,
                          "spec_accepted": 0, "spec_bailouts": 0,
@@ -2345,8 +2356,7 @@ class PagedEngine(_EngineCommon):
                   for st in self.slots]
         toks = self._sample_rows(logits, rids, counts)
         self.counters["decode_steps"] += 1
-        self.counters["decode_kv_tokens"] += sum(
-            int(self.lengths[i]) + 1 for i in active)
+        self._count_kv([int(self.lengths[i]) + 1 for i in active])
         self._count_pages(self._decode_positions(active))
         with self.tracer.span("emit"):
             for i in active:
@@ -2377,19 +2387,34 @@ class PagedEngine(_EngineCommon):
         positions[active, 0] = self.lengths[active]
         return positions
 
+    def _count_kv(self, spans: list[int]) -> None:
+        """Add one decode step's attended tokens, each active row's span:
+        all of it in a layer without a window (``decode_kv_tokens``), at
+        most the window in a window layer (``decode_window_kv_tokens``)."""
+        self.counters["decode_kv_tokens"] += sum(spans)
+        if self._window:
+            self.counters["decode_window_kv_tokens"] += sum(
+                min(n, self._window) for n in spans)
+
     def _count_pages(self, positions: np.ndarray) -> None:
         """Add one decode step's paged BESF kernel grid, ``max_slots x
         max_blocks_per_req`` steps a layer, and the steps inside each
-        slot's page range (``kernels/paged_decode.py:page_bounds``, a
-        layer without a window), from the step's query positions as the
-        attention layer turns them into fill levels: position + 1, or 0
-        for a padding query."""
+        slot's page range (``kernels/paged_decode.py:page_bounds``) of a
+        layer without a window and, where the model has them, of a window
+        layer, from the step's query positions as the attention layer
+        turns them into fill levels: position + 1, or 0 for a padding
+        query."""
         real = positions != POS_SENTINEL
         q_pos = np.where(real, positions, 0)
-        lo, hi = page_bounds(np.where(real, q_pos + 1, 0), q_pos,
-                             self._page, xp=np)
+        fill = np.where(real, q_pos + 1, 0)
+        lo, hi = page_bounds(fill, q_pos, self._page, xp=np)
         self.counters["decode_page_slots"] += self.table.size
         self.counters["decode_pages_reached"] += int((hi - lo).sum())
+        if self._window:
+            lo, hi = page_bounds(fill, q_pos, self._page, self._window,
+                                 xp=np)
+            self.counters["decode_window_pages_reached"] += int(
+                (hi - lo).sum())
 
     def programs(self) -> dict:
         """The jitted forwards this engine serves with, each with
@@ -2558,8 +2583,8 @@ class PagedEngine(_EngineCommon):
                                  rids.reshape(-1),
                                  counts.reshape(-1)).reshape(B, Sq)
         self.counters["decode_steps"] += 1
-        self.counters["decode_kv_tokens"] += sum(
-            int(self.lengths[i]) + 1 + len(drafts[i]) for i in active)
+        self._count_kv([int(self.lengths[i]) + 1 + len(drafts[i])
+                        for i in active])
         self._count_pages(positions)
 
         for i in active:
